@@ -21,6 +21,23 @@ from repro.data import (ClientBatcher, label_skew_partition,  # noqa: E402
 from repro.models import build_model  # noqa: E402
 
 ARTIFACTS = os.path.join(os.path.dirname(__file__), "artifacts")
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def use_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at its one place.
+
+    Where JAX_COMPILATION_CACHE_DIR is set, JAX reads it itself and this
+    sets nothing; otherwise the cache lives at the fixed `<repo>/.jax_cache`,
+    so every later run from this checkout finds what earlier runs compiled.
+    Entry points call this before their first compile; importing a module
+    never does. Returns the cache directory in use.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(REPO, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def paper_problem(model_name: str = "paper_logistic", *, n_clients: int = 100,

@@ -61,6 +61,9 @@ def main() -> None:
     selected = names if args.only is None else (args.only,)
 
     import importlib
+
+    from common import use_compile_cache
+    use_compile_cache()
     print("name,us_per_call,derived")
     failed = []
     for name in selected:

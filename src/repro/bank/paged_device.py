@@ -198,6 +198,12 @@ class PagedDeviceBank(MemoryBank):
         self.lp = -(-n_clients // ps)
         self.n_slots = (self.lp if self._n_slots_cfg is None
                         else self._n_slots_cfg)
+        from repro.kernels.backend import interpret_default
+        if self._pallas() and not interpret_default():
+            # refuse at build time what one compiled kernel call could not
+            # hold; a fleet's K stacked tables are checked at the call
+            from repro.kernels.bank_scatter import check_page_table_fits
+            check_page_table_fits(self.lp + 1, ps)
         self.dummy_lrow = self.lp * ps
         self.sentinel = self.n_slots         # the dummy slot doubles as it
         n_rows = (self.n_slots + 1) * ps

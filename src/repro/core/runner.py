@@ -534,6 +534,7 @@ def run_fl(*, model, algo, batcher, schedule: Callable, n_rounds: int,
            params=None, uses_update_clock: bool = False,
            cohort_capacity: int | None = None, engine: str = "loop",
            scan_chunk: int = 64, checkpoint=None, mesh=None, cfg=None,
+           return_state: bool = False,
            verbose: bool = False) -> tuple[Any, FLHistory]:
     """Run T round-synchronous rounds of federated training.
 
@@ -602,9 +603,16 @@ def run_fl(*, model, algo, batcher, schedule: Callable, n_rounds: int,
     Sharded client-axis reductions group partial sums per device, so
     trajectories match single-device runs to fp32 reduction-order
     tolerance, not bitwise (tests/test_sharded_scan.py pins both).
+
+    `return_state=True` returns ``(params, history, state)``: the
+    algorithm's state as the last round left it (MIFA's update array, a
+    bank's rows and G_sum), where the run placed it — under `mesh`, with
+    the carry's shardings. Not for simulated runs.
     """
     if (participation is None) == (scenario is None):
         raise ValueError("pass exactly one of participation= or scenario=")
+    if return_state and sim is not None:
+        raise ValueError("return_state= is not supported for simulated runs")
     if engine not in ("loop", "scan", "scan_strict"):
         raise ValueError(f"unknown engine {engine!r}: expected 'loop', "
                          "'scan', or 'scan_strict'")
@@ -638,6 +646,11 @@ def run_fl(*, model, algo, batcher, schedule: Callable, n_rounds: int,
                          weight_decay=weight_decay, seed=seed, params=params,
                          uses_update_clock=uses_update_clock,
                          cohort_capacity=cohort_capacity, scenario=scenario)
+
+    def finish():
+        out = runner.finalize()
+        return out + (runner.state,) if return_state else out
+
     if sim is not None:
         from repro.sim.compiled import run_sim_scan, sim_scan_supported
         from repro.sim.engine import FedSimEngine
@@ -674,7 +687,7 @@ def run_fl(*, model, algo, batcher, schedule: Callable, n_rounds: int,
             fast_forward_sampler(participation, start_round)
             fast_forward_sampler(runner._scen_sampler, start_round)
         if start_round >= n_rounds:
-            return runner.finalize()
+            return finish()
     if engine != "loop":
         from repro.core.scan_engine import ScanDriver, scan_supported
         ok, why = scan_supported(runner)
@@ -686,7 +699,7 @@ def run_fl(*, model, algo, batcher, schedule: Callable, n_rounds: int,
                 eval_every=eval_every, verbose=verbose,
                 checkpoint=checkpoint, start_round=start_round)
             runner.hist.wall_time = time.time() - t0
-            return runner.finalize()
+            return finish()
         if engine == "scan_strict":
             raise ValueError(f"engine='scan_strict': {why}")
         if checkpoint is not None:
@@ -716,4 +729,4 @@ def run_fl(*, model, algo, batcher, schedule: Callable, n_rounds: int,
                 print(f"  round {t:5d} train={runner.hist.train_loss[-1]:.4f} "
                       f"eval={el:.4f} acc={ea:.4f} active={n_active}")
     runner.hist.wall_time = time.time() - t0
-    return runner.finalize()
+    return finish()

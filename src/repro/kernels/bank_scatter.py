@@ -24,10 +24,19 @@ Padded cohort slots (valid=0) must point `ids` at a dedicated dummy row
 row's own old value back (a no-op, deterministic even when every pad slot
 aliases the same dummy row) and contributes zero to the delta sum.
 
-Blocks are (1, block_m): a single bank row per step, since gathered rows
-are not contiguous. On real TPUs a (1, 512) f32 tile is below the (8, 128)
-sublane optimum — acceptable for a DMA-bound gather (same trade the
-embedding-lookup kernels make).
+Row layout: the kernels see a bank of R rows as an (R, 1, M) array and
+move one row per step in a (None, 1, block_m) block. The TPU compiler
+takes a block whose last two dims are (1, block_m) of a (…, 1, M) array —
+the second-minor dim equals the array's — but refuses a (1, block_m)
+block of an (R, M) array (its second-minor 1 neither divides by 8 nor
+equals R). The public wrappers keep the 2-D (R, M) interface and reshape,
+which is free whenever R's tiled layout needs no padding. One row per
+step is below the (8, 128) sublane optimum — acceptable for a DMA-bound
+gather (the same trade embedding-lookup kernels make).
+
+Page tables and row ids reach the index maps through scalar memory
+(SMEM); `check_page_table_fits` bounds the page-table size the compiled
+paged kernels accept.
 """
 from __future__ import annotations
 
@@ -40,23 +49,65 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.backend import resolve_interpret
 
+# Scalar prefetch copies page tables into SMEM. The bound is the TPU v5e's
+# (1 MiB of SMEM; other generations differ): a 3·2**16-entry int32 table
+# (768 KiB) compiles beside 256 cohort ids and masks; 2**18 entries (the
+# whole 1 MiB) is refused by the compiler.
+SMEM_PAGE_TABLE_ENTRIES = 3 << 16
 
-def _kernel(ids_ref, valid_ref, u_ref, bank_ref, bank_out_ref, dsum_ref):
-    a = pl.program_id(1)
-    valid = valid_ref[a] > 0
-    old = bank_ref[...]                                   # (1, bm) bank dtype
+
+def check_page_table_fits(n_entries: int, page_size: int) -> None:
+    """Raise when a compiled paged kernel's page table cannot fit SMEM.
+
+    `n_entries` counts every table entry the kernel prefetches (K tables of
+    P entries for the fleet kernel). Fails here, naming the remedy, rather
+    than inside the TPU compiler.
+    """
+    if n_entries > SMEM_PAGE_TABLE_ENTRIES:
+        raise ValueError(
+            f"page table of {n_entries} entries exceeds the "
+            f"{SMEM_PAGE_TABLE_ENTRIES} that fit the TPU's scalar memory "
+            f"(SMEM) beside the cohort ids; raise page_size (now "
+            f"{page_size}) so that N / page_size fits, or build the bank "
+            "with use_pallas=False")
+
+
+def _paged_interpret(page_table, page_size: int, interpret) -> bool:
+    """Resolve interpret mode for a paged kernel call; a compiled call
+    first checks that every table entry it prefetches fits SMEM."""
+    interpret = resolve_interpret(interpret)
+    if not interpret:
+        check_page_table_fits(page_table.size, page_size)
+    return interpret
+
+
+def _update_row(valid, first, u_ref, old_ref, out_ref, dsum_ref):
+    """One cohort row's column tile: masked delta into the resident
+    delta-sum tile, fresh (or old, when invalid) value written back."""
+    old = old_ref[...]                                    # (1, bm) bank dtype
     u = u_ref[...]                                        # (1, bm) f32
 
-    @pl.when(a == 0)
+    @pl.when(first)
     def _init():
         dsum_ref[...] = jnp.zeros_like(dsum_ref)
 
     # delta uses the *stored* (dtype-cast) value, not the raw f32 update —
     # keeps G_sum == Σ rows exact for bf16 banks (same as the jnp path)
-    u_st = u.astype(bank_ref.dtype)
+    u_st = u.astype(old_ref.dtype)
     dsum_ref[...] += jnp.where(
         valid, u_st.astype(jnp.float32) - old.astype(jnp.float32), 0.0)
-    bank_out_ref[...] = jnp.where(valid, u_st, old)
+    out_ref[...] = jnp.where(valid, u_st, old)
+
+
+def _rows3(x):
+    """(..., R, M) -> (..., R, 1, M): the row layout the kernels block."""
+    return x.reshape(x.shape[:-1] + (1, x.shape[-1]))
+
+
+def _kernel(ids_ref, valid_ref, u_ref, bank_ref, bank_out_ref, dsum_ref):
+    a = pl.program_id(1)
+    _update_row(valid_ref[a] > 0, a == 0, u_ref, bank_ref, bank_out_ref,
+                dsum_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "interpret"))
@@ -68,26 +119,28 @@ def _bank_scatter(bank, updates, ids, valid, *, block_m, interpret):
     assert updates.shape == (c, m), (updates.shape, (c, m))
     assert ids.shape == valid.shape == (c,), (ids.shape, valid.shape)
 
+    row = (None, 1, bm)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                            # ids, valid
         grid=(m // bm, c),
         in_specs=[
-            pl.BlockSpec((1, bm), lambda j, a, ids, valid: (a, j)),
-            pl.BlockSpec((1, bm), lambda j, a, ids, valid: (ids[a], j)),
+            pl.BlockSpec(row, lambda j, a, ids, valid: (a, 0, j)),
+            pl.BlockSpec(row, lambda j, a, ids, valid: (ids[a], 0, j)),
         ],
         out_specs=[
-            pl.BlockSpec((1, bm), lambda j, a, ids, valid: (ids[a], j)),
+            pl.BlockSpec(row, lambda j, a, ids, valid: (ids[a], 0, j)),
             pl.BlockSpec((1, bm), lambda j, a, ids, valid: (0, j)),
         ],
     )
-    return pl.pallas_call(
+    new_bank, dsum = pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((r, m), bank.dtype),
+        out_shape=[jax.ShapeDtypeStruct((r, 1, m), bank.dtype),
                    jax.ShapeDtypeStruct((1, m), jnp.float32)],
         input_output_aliases={3: 0},                      # bank updated in place
         interpret=interpret,
-    )(ids, valid, updates, bank)
+    )(ids, valid, _rows3(updates), _rows3(bank))
+    return new_bank.reshape(r, m), dsum
 
 
 def bank_scatter(bank: jnp.ndarray, updates: jnp.ndarray, ids: jnp.ndarray,
@@ -115,18 +168,8 @@ def _kernel_batched(ids_ref, valid_ref, u_ref, bank_ref, bank_out_ref,
                     dsum_ref):
     k = pl.program_id(0)
     a = pl.program_id(2)
-    valid = valid_ref[k, a] > 0
-    old = bank_ref[...]                                   # (1, 1, bm)
-    u = u_ref[...]                                        # (1, 1, bm) f32
-
-    @pl.when(a == 0)
-    def _init():
-        dsum_ref[...] = jnp.zeros_like(dsum_ref)
-
-    u_st = u.astype(bank_ref.dtype)
-    dsum_ref[...] += jnp.where(
-        valid, u_st.astype(jnp.float32) - old.astype(jnp.float32), 0.0)
-    bank_out_ref[...] = jnp.where(valid, u_st, old)
+    _update_row(valid_ref[k, a] > 0, a == 0, u_ref, bank_ref, bank_out_ref,
+                dsum_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "interpret"))
@@ -141,28 +184,31 @@ def _bank_scatter_batched(banks, updates, ids, valid, *, block_m, interpret):
     # trial axis outermost, cohort rows innermost: the (k, j) delta-sum tile
     # stays resident in VMEM and accumulates across that trial's cohort,
     # exactly like the single-trial kernel's k-loop
+    row = (None, None, 1, bm)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                            # ids, valid (K, C)
         grid=(K, m // bm, c),
         in_specs=[
-            pl.BlockSpec((1, 1, bm), lambda k, j, a, ids, valid: (k, a, j)),
-            pl.BlockSpec((1, 1, bm),
-                         lambda k, j, a, ids, valid: (k, ids[k, a], j)),
+            pl.BlockSpec(row, lambda k, j, a, ids, valid: (k, a, 0, j)),
+            pl.BlockSpec(row,
+                         lambda k, j, a, ids, valid: (k, ids[k, a], 0, j)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bm),
-                         lambda k, j, a, ids, valid: (k, ids[k, a], j)),
-            pl.BlockSpec((1, 1, bm), lambda k, j, a, ids, valid: (k, 0, j)),
+            pl.BlockSpec(row,
+                         lambda k, j, a, ids, valid: (k, ids[k, a], 0, j)),
+            pl.BlockSpec((None, 1, bm),
+                         lambda k, j, a, ids, valid: (k, 0, j)),
         ],
     )
-    return pl.pallas_call(
+    new_banks, dsum = pl.pallas_call(
         _kernel_batched,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((K, r, m), banks.dtype),
+        out_shape=[jax.ShapeDtypeStruct((K, r, 1, m), banks.dtype),
                    jax.ShapeDtypeStruct((K, 1, m), jnp.float32)],
         input_output_aliases={3: 0},                      # banks in place
         interpret=interpret,
-    )(ids, valid, updates, banks)
+    )(ids, valid, _rows3(updates), _rows3(banks))
+    return new_banks.reshape(K, r, m), dsum
 
 
 def bank_scatter_batched(banks: jnp.ndarray, updates: jnp.ndarray,
@@ -201,7 +247,7 @@ def bank_scatter_batched(banks: jnp.ndarray, updates: jnp.ndarray,
 # stray access reads zeros and writes are no-ops; the bank's `prepare` hook
 # guarantees every *valid* row is resident before a round executes.
 #
-# The kernel bodies are identical to the flat kernels above (read old,
+# The kernel bodies are the flat kernels' `_update_row` (read old,
 # accumulate the masked delta, write the fresh update back in place) — only
 # the addressing differs, which is exactly why paged trajectories stay
 # fp32 bit-exact against the flat bank: reductions run over the cohort axis,
@@ -211,18 +257,8 @@ def bank_scatter_batched(banks: jnp.ndarray, updates: jnp.ndarray,
 def _paged_kernel(pt_ref, lids_ref, valid_ref, u_ref, pages_ref,
                   pages_out_ref, dsum_ref):
     a = pl.program_id(1)
-    valid = valid_ref[a] > 0
-    old = pages_ref[...]                                  # (1, bm) page dtype
-    u = u_ref[...]                                        # (1, bm) f32
-
-    @pl.when(a == 0)
-    def _init():
-        dsum_ref[...] = jnp.zeros_like(dsum_ref)
-
-    u_st = u.astype(pages_ref.dtype)
-    dsum_ref[...] += jnp.where(
-        valid, u_st.astype(jnp.float32) - old.astype(jnp.float32), 0.0)
-    pages_out_ref[...] = jnp.where(valid, u_st, old)
+    _update_row(valid_ref[a] > 0, a == 0, u_ref, pages_ref, pages_out_ref,
+                dsum_ref)
 
 
 @functools.partial(jax.jit,
@@ -237,34 +273,35 @@ def _paged_bank_scatter(pages, updates, page_table, lids, valid, *,
     assert updates.shape == (c, m), (updates.shape, (c, m))
     assert lids.shape == valid.shape == (c,), (lids.shape, valid.shape)
 
+    def _prow(a, pt, lids):
+        return pt[lids[a] // ps] * ps + lids[a] % ps
+
     # the page lookup IS the index map: scalar-prefetched page_table + lids
     # resolve each cohort slot to its physical row before the body runs
+    row = (None, 1, bm)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,                            # pt, lids, valid
         grid=(m // bm, c),
         in_specs=[
-            pl.BlockSpec((1, bm), lambda j, a, pt, lids, valid: (a, j)),
-            pl.BlockSpec(
-                (1, bm),
-                lambda j, a, pt, lids, valid:
-                    (pt[lids[a] // ps] * ps + lids[a] % ps, j)),
+            pl.BlockSpec(row, lambda j, a, pt, lids, valid: (a, 0, j)),
+            pl.BlockSpec(row, lambda j, a, pt, lids, valid:
+                         (_prow(a, pt, lids), 0, j)),
         ],
         out_specs=[
-            pl.BlockSpec(
-                (1, bm),
-                lambda j, a, pt, lids, valid:
-                    (pt[lids[a] // ps] * ps + lids[a] % ps, j)),
+            pl.BlockSpec(row, lambda j, a, pt, lids, valid:
+                         (_prow(a, pt, lids), 0, j)),
             pl.BlockSpec((1, bm), lambda j, a, pt, lids, valid: (0, j)),
         ],
     )
-    return pl.pallas_call(
+    new_pages, dsum = pl.pallas_call(
         _paged_kernel,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((r, m), pages.dtype),
+        out_shape=[jax.ShapeDtypeStruct((r, 1, m), pages.dtype),
                    jax.ShapeDtypeStruct((1, m), jnp.float32)],
         input_output_aliases={4: 0},                      # pages in place
         interpret=interpret,
-    )(page_table, lids, valid, updates, pages)
+    )(page_table, lids, valid, _rows3(updates), _rows3(pages))
+    return new_pages.reshape(r, m), dsum
 
 
 def paged_bank_scatter(pages: jnp.ndarray, updates: jnp.ndarray,
@@ -280,11 +317,11 @@ def paged_bank_scatter(pages: jnp.ndarray, updates: jnp.ndarray,
     (new_pages, delta_sum (M,) f32) — per slot exactly `bank_scatter` on the
     physically-addressed rows.
     """
+    interpret = _paged_interpret(page_table, page_size, interpret)
     new_pages, dsum = _paged_bank_scatter(
         pages, updates.astype(jnp.float32), page_table.astype(jnp.int32),
         lids.astype(jnp.int32), valid.astype(jnp.int32),
-        page_size=page_size, block_m=block_m,
-        interpret=resolve_interpret(interpret))
+        page_size=page_size, block_m=block_m, interpret=interpret)
     return new_pages, dsum[0]
 
 
@@ -303,26 +340,23 @@ def _paged_bank_gather(pages, page_table, lids, *, page_size, block_m,
     bm = min(block_m, m)
     assert m % bm == 0, (m, bm)
 
+    row = (None, 1, bm)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                            # pt, lids
         grid=(m // bm, c),
         in_specs=[
-            pl.BlockSpec(
-                (1, bm),
-                lambda j, a, pt, lids:
-                    (pt[lids[a] // ps] * ps + lids[a] % ps, j)),
+            pl.BlockSpec(row, lambda j, a, pt, lids:
+                         (pt[lids[a] // ps] * ps + lids[a] % ps, 0, j)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, bm), lambda j, a, pt, lids: (a, j)),
-        ],
+        out_specs=[pl.BlockSpec(row, lambda j, a, pt, lids: (a, 0, j))],
     )
     (out,) = pl.pallas_call(
         _paged_gather_kernel,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((c, m), jnp.float32)],
+        out_shape=[jax.ShapeDtypeStruct((c, 1, m), jnp.float32)],
         interpret=interpret,
-    )(page_table, lids, pages)
-    return out
+    )(page_table, lids, _rows3(pages))
+    return out.reshape(c, m)
 
 
 def paged_bank_gather(pages: jnp.ndarray, page_table: jnp.ndarray,
@@ -333,28 +367,18 @@ def paged_bank_gather(pages: jnp.ndarray, page_table: jnp.ndarray,
     Non-resident logical pages read the dummy slot (exact zeros by the
     bank's invariant); the caller masks or `prepare`s as needed.
     """
+    interpret = _paged_interpret(page_table, page_size, interpret)
     return _paged_bank_gather(
         pages, page_table.astype(jnp.int32), lids.astype(jnp.int32),
-        page_size=page_size, block_m=block_m,
-        interpret=resolve_interpret(interpret))
+        page_size=page_size, block_m=block_m, interpret=interpret)
 
 
 def _paged_kernel_batched(pt_ref, lids_ref, valid_ref, u_ref, pages_ref,
                           pages_out_ref, dsum_ref):
     k = pl.program_id(0)
     a = pl.program_id(2)
-    valid = valid_ref[k, a] > 0
-    old = pages_ref[...]                                  # (1, 1, bm)
-    u = u_ref[...]                                        # (1, 1, bm) f32
-
-    @pl.when(a == 0)
-    def _init():
-        dsum_ref[...] = jnp.zeros_like(dsum_ref)
-
-    u_st = u.astype(pages_ref.dtype)
-    dsum_ref[...] += jnp.where(
-        valid, u_st.astype(jnp.float32) - old.astype(jnp.float32), 0.0)
-    pages_out_ref[...] = jnp.where(valid, u_st, old)
+    _update_row(valid_ref[k, a] > 0, a == 0, u_ref, pages_ref,
+                pages_out_ref, dsum_ref)
 
 
 @functools.partial(jax.jit,
@@ -372,32 +396,31 @@ def _paged_bank_scatter_batched(pages, updates, page_table, lids, valid, *,
     def _prow(k, a, pt, lids):
         return pt[k, lids[k, a] // ps] * ps + lids[k, a] % ps
 
+    row = (None, None, 1, bm)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,                            # pt, lids, valid
         grid=(K, m // bm, c),
         in_specs=[
-            pl.BlockSpec((1, 1, bm),
-                         lambda k, j, a, pt, lids, valid: (k, a, j)),
-            pl.BlockSpec((1, 1, bm),
-                         lambda k, j, a, pt, lids, valid:
-                             (k, _prow(k, a, pt, lids), j)),
+            pl.BlockSpec(row, lambda k, j, a, pt, lids, valid: (k, a, 0, j)),
+            pl.BlockSpec(row, lambda k, j, a, pt, lids, valid:
+                         (k, _prow(k, a, pt, lids), 0, j)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bm),
-                         lambda k, j, a, pt, lids, valid:
-                             (k, _prow(k, a, pt, lids), j)),
-            pl.BlockSpec((1, 1, bm),
+            pl.BlockSpec(row, lambda k, j, a, pt, lids, valid:
+                         (k, _prow(k, a, pt, lids), 0, j)),
+            pl.BlockSpec((None, 1, bm),
                          lambda k, j, a, pt, lids, valid: (k, 0, j)),
         ],
     )
-    return pl.pallas_call(
+    new_pages, dsum = pl.pallas_call(
         _paged_kernel_batched,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((K, r, m), pages.dtype),
+        out_shape=[jax.ShapeDtypeStruct((K, r, 1, m), pages.dtype),
                    jax.ShapeDtypeStruct((K, 1, m), jnp.float32)],
         input_output_aliases={4: 0},                      # pages in place
         interpret=interpret,
-    )(page_table, lids, valid, updates, pages)
+    )(page_table, lids, valid, _rows3(updates), _rows3(pages))
+    return new_pages.reshape(K, r, m), dsum
 
 
 def paged_bank_scatter_batched(pages: jnp.ndarray, updates: jnp.ndarray,
@@ -412,9 +435,9 @@ def paged_bank_scatter_batched(pages: jnp.ndarray, updates: jnp.ndarray,
     lids/valid (K, C). Returns (new_pages (K, R, M), delta_sum (K, M) f32),
     per trial k exactly `paged_bank_scatter(pages[k], ...)`.
     """
+    interpret = _paged_interpret(page_table, page_size, interpret)
     new_pages, dsum = _paged_bank_scatter_batched(
         pages, updates.astype(jnp.float32), page_table.astype(jnp.int32),
         lids.astype(jnp.int32), valid.astype(jnp.int32),
-        page_size=page_size, block_m=block_m,
-        interpret=resolve_interpret(interpret))
+        page_size=page_size, block_m=block_m, interpret=interpret)
     return new_pages, dsum[:, 0]

@@ -2,12 +2,9 @@
 
 Functions, not module-level constants: importing this module never touches JAX
 device state. The dry-run sets XLA_FLAGS for 512 host devices *before* any JAX
-import; smoke tests and benchmarks see the single real CPU device.
-
-Construction goes through version-portable helpers: the installed JAX may or
-may not expose `jax.sharding.AxisType` / accept `axis_types=` in
-`jax.make_mesh`, and `AbstractMesh` switched from positional (shape, names)
-to a single ((name, size), ...) shape_tuple.
+import; smoke tests and benchmarks see the single real CPU device. Every mesh
+axis is `AxisType.Auto`: the sharding rules place arrays with explicit
+`NamedSharding`s and leave the rest to the partitioner.
 """
 from __future__ import annotations
 
@@ -36,31 +33,17 @@ def _validate_axes(shape: tuple, axes: tuple) -> None:
 
 
 def make_abstract_mesh(shape: tuple, axes: tuple):
-    """AbstractMesh across JAX versions.
-
-    Newer JAX takes one shape_tuple of (name, size) pairs; older releases
-    took positional (axis_shapes, axis_names).
-    """
+    """Device-free `AbstractMesh` of `shape` over axis names `axes`."""
     _validate_axes(shape, axes)
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(zip(axes, shape)))
-    except TypeError:
-        return AbstractMesh(shape, axes)
+    return jax.sharding.AbstractMesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def _make_mesh(shape: tuple, axes: tuple, devices=None):
     _validate_axes(shape, axes)
-    kwargs = {} if devices is None else {"devices": devices}
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(axis_type.Auto,) * len(axes),
-                                 **kwargs)
-        except TypeError:
-            pass  # this jax.make_mesh predates axis_types
-    return jax.make_mesh(shape, axes, **kwargs)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -60,6 +60,28 @@ if os.environ.get(MESH8_WORLD_ENV):
 
 import pytest  # noqa: E402
 
+# A vmapped fleet and K sequential runs are different programs: under vmap a
+# dot with the shared (unbatched) data batch folds the trial axis into the
+# matmul, and XLA may sum it in another order. Parameters and losses agree
+# to the fp32 reduction-order band of tests/test_sharded_scan.py; the
+# mask-derived integers (active counts) agree exactly.
+FLEET_RTOL, FLEET_ATOL = 2e-5, 1e-6
+
+
+def assert_fleet_trial_close(fleet, seq_run, k):
+    """Trial k of a fleet result `(params, FleetHistory)` against the
+    sequential run `(params, FLHistory)` with the same seed and trace."""
+    import jax
+    import numpy as np
+    params_k = jax.tree.map(lambda leaf: leaf[k], fleet[0])
+    for a, b in zip(jax.tree.leaves(params_k), jax.tree.leaves(seq_run[0])):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=FLEET_RTOL, atol=FLEET_ATOL)
+    hist_k = fleet[1].trial(k)
+    np.testing.assert_allclose(hist_k.train_loss, seq_run[1].train_loss,
+                               rtol=FLEET_RTOL, atol=FLEET_ATOL)
+    assert hist_k.n_active == seq_run[1].n_active
+
 
 def pytest_addoption(parser):
     parser.addoption("--runslow", action="store_true", default=False,

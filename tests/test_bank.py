@@ -450,3 +450,23 @@ def test_paged_pallas_path_matches_jnp():
     for a, b in zip(jax.tree.leaves(b1.gather(s1, np.arange(N))),
                     jax.tree.leaves(b2.gather(s2, np.arange(N)))):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_paged_page_table_beyond_smem_raises_naming_page_size():
+    """A page table the compiled kernels could not hold in the TPU's scalar
+    memory is refused when the bank is built, naming page_size — not
+    inside the compiler. The jnp path and interpret mode have no bound."""
+    from repro.kernels.backend import use_pallas
+    from repro.kernels.bank_scatter import SMEM_PAGE_TABLE_ENTRIES
+    params = _tree(jax.random.PRNGKey(0))
+    too_many = SMEM_PAGE_TABLE_ENTRIES * 2        # page_size=2: entries = N/2
+    use_pallas(True)                              # as on a TPU: compiled
+    try:
+        with pytest.raises(ValueError, match="page_size"):
+            PagedDeviceBank(page_size=2, n_slots=2).init(params, too_many)
+        PagedDeviceBank(page_size=4, n_slots=2).init(params, too_many)
+        PagedDeviceBank(page_size=2, n_slots=2,
+                        use_pallas=False).init(params, too_many)
+    finally:
+        use_pallas(None)
+    PagedDeviceBank(page_size=2, n_slots=2).init(params, too_many)

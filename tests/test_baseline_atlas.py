@@ -28,6 +28,7 @@ from repro.core import (algorithm_assumes, algorithm_names, make_algorithm,
 from repro.core.runner import _reset_fallback_warnings, warn_engine_fallback
 from repro.fleet import Trial, run_fleet
 from repro.scenarios import make_scenario, scenario_names
+from conftest import assert_fleet_trial_close
 
 N = 6
 
@@ -60,14 +61,15 @@ def test_every_algorithm_runs_every_scenario(tiny_problem, algo_name,
 
 
 # --------------------------------------------------------------------------- #
-# fleet-vs-sequential bit-exactness for the new baselines (scan engine)
+# fleet-vs-sequential agreement for the new baselines (scan engine)
 # --------------------------------------------------------------------------- #
 
 @pytest.mark.parametrize("algo_name", ["fedar", "ca_fed"])
 def test_new_baselines_fleet_scan_bitexact_vs_sequential(tiny_problem,
                                                          algo_name):
-    """fp32 bit-exact: K seeds of FedAR / CA-Fed as one jit(scan(vmap))
-    program reproduce the sequential per-seed `run_fl` runs exactly."""
+    """K seeds of FedAR / CA-Fed as one jit(scan(vmap)) program reproduce
+    the sequential per-seed `run_fl` runs within the fp32 reduction-order
+    band, active counts exact."""
     model, batcher = tiny_problem(n_clients=N)
     algo = make_algorithm(algo_name, n=N)
     kw = dict(model=model, batcher=batcher,
@@ -85,12 +87,7 @@ def test_new_baselines_fleet_scan_bitexact_vs_sequential(tiny_problem,
                               for k in range(3)],
                       engine="scan", **kw)
     for k in range(3):
-        params_k = jax.tree.map(lambda leaf: leaf[k], fleet[0])
-        for a, b in zip(jax.tree.leaves(params_k),
-                        jax.tree.leaves(seq[k][0])):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-        assert fleet[1].trial(k).train_loss == seq[k][1].train_loss
-        assert fleet[1].trial(k).n_active == seq[k][1].n_active
+        assert_fleet_trial_close(fleet, seq[k], k)
 
 
 # --------------------------------------------------------------------------- #

@@ -1,9 +1,11 @@
-"""Fleet executor: vmapped K-trial runs are bit-exact per trial.
+"""Fleet executor: vmapped K-trial runs match sequential runs per trial.
 
 The anchor property for `repro.fleet`: for every algorithm on the vmapped
-path, running K trials as one jitted program yields *exactly* (fp32
-bit-exact) the parameters and loss history that K sequential `run_fl` calls
-produce — so sweep results never depend on which execution path ran them.
+path, running K trials as one jitted program yields the parameters and loss
+history that K sequential `run_fl` calls produce, within the fp32
+reduction-order band (vmap may regroup a dot's sum; conftest
+`assert_fleet_trial_close`), with the active counts exact — so sweep
+results never depend on which execution path ran them.
 
 The property is enforced twice: on deterministic trace sets covering the
 degenerate shapes (all-dark trials, full cohorts, mixed cohort sizes
@@ -20,6 +22,7 @@ from repro.core import (MIFA, BiasedFedAvg, FedAvgSampling,
                         TraceParticipation, run_fl)
 from repro.fleet import (FleetRunner, Trial, expand_grid, make_fleet_eval,
                          run_fleet)
+from conftest import assert_fleet_trial_close
 
 try:
     from hypothesis import given, settings
@@ -44,8 +47,8 @@ def _run_pair(tiny_problem, algo_factory, traces, clock):
 
     The cohort capacity is pinned to one shared value on BOTH paths: pad
     slots are mathematically inert, but fp32 reduction grouping depends on
-    the padded length, so bit-exact comparison needs matching pad widths
-    (run_fl's docstring spells this out).
+    the padded length, so matching pad widths keep the two paths' sums
+    grouped alike (run_fl's docstring spells this out).
     """
     model, batcher = tiny_problem(n_clients=N)
     kw = dict(model=model, batcher=batcher, schedule=lambda t: 0.1 / (1 + t),
@@ -63,16 +66,11 @@ def _run_pair(tiny_problem, algo_factory, traces, clock):
 
 
 def _assert_trial_exact(seq, fleet, k):
-    params_k = jax.tree.map(lambda l: l[k], fleet[0])
-    for a, b in zip(jax.tree.leaves(params_k), jax.tree.leaves(seq[k][0])):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    hist_k = fleet[1].trial(k)
-    assert hist_k.train_loss == seq[k][1].train_loss
-    assert hist_k.n_active == seq[k][1].n_active
+    assert_fleet_trial_close(fleet, seq[k], k)
 
 
 # --------------------------------------------------------------------------- #
-# bit-exact equivalence — deterministic traces, always run
+# equivalence — deterministic traces, always run
 # --------------------------------------------------------------------------- #
 
 @pytest.mark.parametrize("name", list(ALGOS))
@@ -98,7 +96,7 @@ def test_fleet_mixed_cohort_sizes_share_capacity(tiny_problem):
 
 
 # --------------------------------------------------------------------------- #
-# bit-exact equivalence — hypothesis-generated traces (CI)
+# equivalence — hypothesis-generated traces (CI)
 # --------------------------------------------------------------------------- #
 
 if HAVE_HYPOTHESIS:

@@ -90,6 +90,28 @@ def test_scan_bitexact_vs_loop_participation(tiny_problem, name):
     _assert_same(loop, scan)
 
 
+@pytest.mark.parametrize("name", ["mifa_array", "banked_dense"])
+def test_return_state_is_the_final_state(tiny_problem, name):
+    """return_state=True hands back the algorithm state the last round left
+    — the same from both engines — and the run's params and history as
+    without it."""
+    kw = _kw(tiny_problem)
+    runs = {engine: run_fl(algo=ALGOS[name](), engine=engine, scan_chunk=4,
+                           scenario=_ge(), return_state=True, **kw)
+            for engine in ("loop", "scan")}
+    for engine, (params, hist, state) in runs.items():
+        _assert_same((params, hist), run_fl(algo=ALGOS[name](),
+                                            engine=engine, scan_chunk=4,
+                                            scenario=_ge(), **kw))
+    sa, sb = runs["loop"][2], runs["scan"][2]
+    assert jax.tree.structure(sa) == jax.tree.structure(sb)
+    for a, b in zip(jax.tree.leaves(sa), jax.tree.leaves(sb)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    rows = [leaf for leaf in jax.tree.leaves(sb) if leaf.ndim
+            and leaf.shape[0] in (N, N + 1)]
+    assert rows and any(np.asarray(r).any() for r in rows)
+
+
 @pytest.mark.parametrize("chunk", [1, 7, T])
 def test_scan_chunk_boundary_invariance(tiny_problem, chunk):
     """scan_chunk is an execution detail: {1, 7, T} give identical runs."""

@@ -22,6 +22,7 @@ from repro.scenarios import (Bernoulli, GilbertElliott, HostSampler,
                              Scenario, make_scenario, register,
                              scenario_names)
 from repro.scenarios.base import as_process
+from conftest import assert_fleet_trial_close
 
 N = 6
 
@@ -143,7 +144,8 @@ def _ge(seed, burst=3.0):
 
 def test_fleet_bitexact_vs_sequential_jit_native(tiny_problem):
     """K trials under a jit-native Gilbert–Elliott scenario: the vmapped
-    fleet reproduces sequential `run_fl(scenario=...)` bit-for-bit."""
+    fleet reproduces sequential `run_fl(scenario=...)` within the fp32
+    reduction-order band, active counts exact."""
     model, batcher = tiny_problem(n_clients=N)
     kw = dict(model=model, batcher=batcher,
               schedule=lambda t: 0.1 / (1 + t), n_rounds=4,
@@ -154,12 +156,7 @@ def test_fleet_bitexact_vs_sequential_jit_native(tiny_problem):
                       trials=[Trial(seed=k, scenario=_ge(k))
                               for k in range(3)], **kw)
     for k in range(3):
-        params_k = jax.tree.map(lambda l: l[k], fleet[0])
-        for a, b in zip(jax.tree.leaves(params_k),
-                        jax.tree.leaves(seq[k][0])):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-        assert fleet[1].trial(k).train_loss == seq[k][1].train_loss
-        assert fleet[1].trial(k).n_active == seq[k][1].n_active
+        assert_fleet_trial_close(fleet, seq[k], k)
 
 
 def test_fleet_grid_three_scenario_types_sample_in_jit(tiny_problem,

@@ -206,6 +206,28 @@ def test_run_fl_wires_mesh_into_bank(mesh8_world, tiny_problem):
     assert algo.bank.n_rows == 10      # N+1=9 padded up to divide d=2
 
 
+@mesh8
+def test_chip_smoke_mesh_phase(mesh8_world):
+    """Rehearsal of `chip_smoke.py --chips 4`: its mesh phase on four of the
+    world's host devices at a tiny size, under the script's own checks;
+    the client-axis rows must spread evenly over the four devices."""
+    sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
+    import chip_smoke
+    out = chip_smoke.mesh_phase(data=4, n_clients=8, n_rounds=8,
+                                scan_chunk=4, n_per_class=20, batch_size=8,
+                                k_steps=2, use_pallas=True)
+    for name in ("mifa_array", "banked_dense"):
+        per_device = out[name]["client_rows"]
+        assert len(per_device) == 4
+        assert len(set(per_device.values())) == 1, per_device
+        assert min(per_device.values()) > 0
+        # the client rows are part of the returned state, read in full
+        assert all(out[name]["state"][d] >= per_device[d]
+                   for d in per_device)
+        assert out[name]["agreement"]["max_rel_param_diff"] <= \
+            chip_smoke.RTOL
+
+
 # --------------------------------------------------------------------------- #
 # the subprocess proxy — the only test here that runs in plain tier-1
 # --------------------------------------------------------------------------- #

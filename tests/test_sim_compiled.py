@@ -23,6 +23,7 @@ from repro.sim import (BufferedKofN, Deadline, FedSimEngine, Impatient,
                        tiered_shifted_exponential)
 from repro.sim.compiled import run_sim_scan
 from repro.sim.engine import LATE
+from conftest import FLEET_ATOL, FLEET_RTOL
 
 N, T = 9, 12
 CONFIG = SimConfig(epoch_s=4.0, server_overhead_s=0.1,
@@ -290,8 +291,11 @@ def test_sim_fleet_matches_single_runs(tiny_problem):
                        schedule=schedule, n_rounds=T, scenario=tr.scenario,
                        sim=sim, seed=tr.seed, engine="scan_strict",
                        scan_chunk=5)
+        # event times are exact; losses within the fleet's reduction-order
+        # band (conftest FLEET_RTOL)
         np.testing.assert_array_equal(st["sim_seconds"][k], h1.sim_seconds)
-        np.testing.assert_array_equal(st["train_loss"][k], h1.train_loss)
+        np.testing.assert_allclose(st["train_loss"][k], h1.train_loss,
+                                   rtol=FLEET_RTOL, atol=FLEET_ATOL)
 
 
 def test_sim_fleet_rejects_mixed_latency_classes(tiny_problem):
