@@ -51,6 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.bank.base import MemoryBank, broadcast_valid, check_unique_ids
 from repro.bank.dense import _scatter_jnp, _traced
 from repro.core import quantized_memory as qm
@@ -243,7 +244,16 @@ class PagedDeviceBank(MemoryBank):
         never written) in one batched device write per leaf. Returns the
         new state; a no-op (same state object) when everything is already
         resident. Raises when the working set cannot fit in `n_slots`.
+
+        Span ``paging`` (`repro.spans`), with children ``victims`` (slot
+        and victim choice), ``spill`` (the device→host read of the evicted
+        pages, counted in ``d2h_bytes``) and ``page_upload`` (the faulted
+        pages' host→device write and the page table, in ``h2d_bytes``).
         """
+        with spans.span("paging"):
+            return self._prepare(state, ids)
+
+    def _prepare(self, state: dict, ids) -> dict:
         ps = self.page_size
         ids = np.asarray(ids).reshape(-1)
         ids = ids[(ids >= 0) & (ids < self.n)]
@@ -262,10 +272,24 @@ class PagedDeviceBank(MemoryBank):
         if not missing:
             return state
         self.faults += len(missing)
+        with spans.span("victims"):
+            assign, evict = self._place(need, missing)
         fleet = self._is_fleet(state)
+        pages_leaves, treedef = jax.tree.flatten(state["pages"])
+        sc_leaves = (treedef.flatten_up_to(state["scales"])
+                     if self.quantized else None)
+        if evict:
+            with spans.span("spill"):
+                self._spill_pages(evict, pages_leaves, sc_leaves, fleet)
+        with spans.span("page_upload"):
+            return self._upload(state, assign, pages_leaves, sc_leaves,
+                                treedef, fleet)
 
-        # 1) host bookkeeping: pick a slot per faulted page, evicting
-        #    deterministic-LRU victims (oldest timestamp, ties by page id)
+    def _place(self, need, missing):
+        """Step 1, host bookkeeping: a slot per faulted page, evicting
+        deterministic-LRU victims (oldest timestamp, ties by page id).
+        Returns the (page, slot) assignments and the (victim, slot)
+        evictions."""
         needset = {int(l) for l in need}
         assign: list[tuple[int, int]] = []   # (lp, slot)
         evict: list[tuple[int, int]] = []    # (victim_lp, slot)
@@ -288,38 +312,39 @@ class PagedDeviceBank(MemoryBank):
                 del self._lru[victim]
                 self.evictions += 1
             assign.append((l, slot))
+        return assign, evict
 
-        pages_leaves, treedef = jax.tree.flatten(state["pages"])
-        sc_leaves = (treedef.flatten_up_to(state["scales"])
-                     if self.quantized else None)
+    def _spill_pages(self, evict, pages_leaves, sc_leaves, fleet) -> None:
+        """Step 2: one batched device->host read for all evicted slots;
+        the row batch is padded to a pow-2 page count with dummy-page
+        reads (discarded here) so XLA sees few distinct gather shapes."""
+        ps = self.page_size
+        ev_rows = np.concatenate(
+            [np.arange(s * ps, (s + 1) * ps) for _, s in evict]
+            + [np.arange(self.n_slots * ps, (self.n_slots + 1) * ps)]
+            * (_pow2_bucket(len(evict)) - len(evict)))
+        ev_pages = [np.asarray(leaf[:, ev_rows] if fleet else leaf[ev_rows])
+                    for leaf in pages_leaves]
+        ev_scales = ([np.asarray(sc[:, ev_rows] if fleet else sc[ev_rows])
+                      for sc in sc_leaves] if self.quantized else [])
+        spans.count("d2h_bytes", sum(a.nbytes for a in ev_pages + ev_scales))
+        for k, (victim, _) in enumerate(evict):
+            sl = (slice(None), slice(k * ps, (k + 1) * ps))
+            blk = sl if fleet else sl[1]
+            entry = {"pages": [p[blk].copy() for p in ev_pages]}
+            if self.quantized:
+                entry["scales"] = [s[blk].copy() for s in ev_scales]
+            self._spill[victim] = entry
 
-        # 2) one batched device->host read for all evicted slots; the row
-        #    batch is padded to a pow-2 page count with dummy-page reads
-        #    (discarded below) so XLA sees few distinct gather shapes
-        if evict:
-            ev_rows = np.concatenate(
-                [np.arange(s * ps, (s + 1) * ps) for _, s in evict]
-                + [np.arange(self.n_slots * ps, (self.n_slots + 1) * ps)]
-                * (_pow2_bucket(len(evict)) - len(evict)))
-            ev_pages = [np.asarray(leaf[:, ev_rows] if fleet
-                                   else leaf[ev_rows])
-                        for leaf in pages_leaves]
-            ev_scales = ([np.asarray(sc[:, ev_rows] if fleet else sc[ev_rows])
-                          for sc in sc_leaves] if self.quantized else None)
-            for k, (victim, _) in enumerate(evict):
-                sl = (slice(None), slice(k * ps, (k + 1) * ps))
-                blk = sl if fleet else sl[1]
-                entry = {"pages": [p[blk].copy() for p in ev_pages]}
-                if self.quantized:
-                    entry["scales"] = [s[blk].copy() for s in ev_scales]
-                self._spill[victim] = entry
-
-        # 3) one batched host->device write for all faulted pages; pages
-        #    with no spill entry (never written, or written only as zeros)
-        #    upload zeros — REQUIRED, the slot may hold stale evicted data.
-        #    The batch is padded to a pow-2 page count with zero writes to
-        #    the dummy page (which is pinned to zero, so they are no-ops)
-        #    to keep the number of distinct scatter shapes XLA compiles low.
+    def _upload(self, state, assign, pages_leaves, sc_leaves, treedef,
+                fleet) -> dict:
+        """Step 3: one batched host->device write for all faulted pages;
+        pages with no spill entry (never written, or written only as
+        zeros) upload zeros — REQUIRED, the slot may hold stale evicted
+        data. The batch is padded to a pow-2 page count with zero writes
+        to the dummy page (which is pinned to zero, so they are no-ops) to
+        keep the number of distinct scatter shapes XLA compiles low."""
+        ps = self.page_size
         n_pad = _pow2_bucket(len(assign)) - len(assign)
         up_rows = np.concatenate(
             [np.arange(s * ps, (s + 1) * ps) for _, s in assign]
@@ -337,6 +362,7 @@ class PagedDeviceBank(MemoryBank):
                               else sp[kind][j])
             blocks += [np.zeros(shape, leaf.dtype)] * n_pad
             vals = np.concatenate(blocks, axis=1 if fleet else 0)
+            spans.count("h2d_bytes", vals.nbytes)
             idx = (slice(None), up_rows) if fleet else up_rows
             return leaf.at[idx].set(jnp.asarray(vals))
 
@@ -352,6 +378,7 @@ class PagedDeviceBank(MemoryBank):
         for l, slot in assign:
             self._pt[l] = slot
             self._slot_lp[slot] = l
+        spans.count("h2d_bytes", self._pt.nbytes)
         pt_dev = jnp.asarray(self._pt)
         if fleet:
             pt_dev = jnp.broadcast_to(pt_dev, state["page_table"].shape)

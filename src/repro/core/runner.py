@@ -148,11 +148,13 @@ def make_dense_round_fn(model, algo, k_steps: int, weight_decay: float):
     never drift apart.
     """
     def round_fn(state, params, batch, active, eta_loc, eta_srv, rng):
-        updates, losses = client_updates(model.loss_fn, params, batch,
-                                         eta_loc, K=k_steps,
-                                         weight_decay=weight_decay)
-        return algo.round_step(state, params, updates, losses, active,
-                               eta_srv, rng)
+        with jax.named_scope("local_update"):
+            updates, losses = client_updates(model.loss_fn, params, batch,
+                                             eta_loc, K=k_steps,
+                                             weight_decay=weight_decay)
+        with jax.named_scope("server_memory"):
+            return algo.round_step(state, params, updates, losses, active,
+                                   eta_srv, rng)
     return round_fn
 
 
@@ -160,8 +162,9 @@ def make_cohort_update_fn(model, k_steps: int, weight_decay: float):
     """Compact cohort local updates: (params, batch (C, ...), eta_loc) ->
     (updates (C, ...), losses (C,)). Pure; shared with the fleet executor."""
     def cohort_updates_fn(params, batch, eta_loc):
-        return client_updates(model.loss_fn, params, batch, eta_loc,
-                              K=k_steps, weight_decay=weight_decay)
+        with jax.named_scope("local_update"):
+            return client_updates(model.loss_fn, params, batch, eta_loc,
+                                  K=k_steps, weight_decay=weight_decay)
     return cohort_updates_fn
 
 
@@ -188,7 +191,8 @@ def make_scenario_round_fn(model, algo, k_steps: int, weight_decay: float,
 
     def round_fn(state, params, batch, scen_state, t, scen_key, eta_loc,
                  eta_srv, rng):
-        mask, scen_state = scen_fn(scen_key, t, scen_state)
+        with jax.named_scope("availability"):
+            mask, scen_state = scen_fn(scen_key, t, scen_state)
         state, params, metrics = base(state, params, batch, mask, eta_loc,
                                       eta_srv, rng)
         return state, params, metrics, scen_state, mask
@@ -293,9 +297,10 @@ def make_cohort_round_fn(model, algo, k_steps: int, weight_decay: float):
     def cohort_round(state, params, batch, padded, valid, eta_loc, eta_srv,
                      rng):
         updates, losses = updates_fn(params, batch, eta_loc)
-        state, mean_g, metrics = algo.round_step_cohort(
-            state, padded, valid, updates, losses, rng=rng)
-        params = apply_mean(params, mean_g, eta_srv)
+        with jax.named_scope("server_memory"):
+            state, mean_g, metrics = algo.round_step_cohort(
+                state, padded, valid, updates, losses, rng=rng)
+            params = apply_mean(params, mean_g, eta_srv)
         return state, params, metrics
 
     return cohort_round

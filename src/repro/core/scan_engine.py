@@ -52,6 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.core.runner import RoundRunner, _pow2_bucket, make_scan_round_fn
 
 
@@ -101,7 +102,15 @@ def chunk_bounds(n_rounds: int, scan_chunk: int, eval_rounds: set,
 
 def _stack(trees: list) -> dict:
     """Stack a list of per-round pytrees along a new leading axis."""
-    return jax.tree.map(lambda *xs: np.stack(xs), *trees)
+    with spans.span("stack"):
+        return jax.tree.map(lambda *xs: np.stack(xs), *trees)
+
+
+def _host_bytes(tree) -> int:
+    """Bytes the host arrays of `tree` take on the device (64-bit values
+    narrowed as JAX narrows them); arrays already on a device count 0."""
+    return sum(x.size * jax.dtypes.canonicalize_dtype(x.dtype).itemsize
+               for x in jax.tree.leaves(tree) if isinstance(x, np.ndarray))
 
 
 def pad_cohort(ids: np.ndarray, cap: int, n_clients: int,
@@ -148,23 +157,39 @@ def run_pipelined_chunks(carry, segments, *, chunk_fn, build_xs, writeback,
     fault the chunk union's pages in while the host still owns the carry;
     its device reads block on the previous chunk only when pages actually
     move. Returns the final carry.
+
+    The loop is the root span ``run`` (`repro.spans`): it counts
+    ``rounds``, ``dispatch`` times the ``chunk_fn`` call (with NumPy xs,
+    handing them to the device, and the enqueue) and adds the xs bytes to
+    ``h2d_bytes``, ``flush`` times each flush (the wait on the device
+    included) and adds the ys bytes it reads to ``d2h_bytes``.
     """
+    def flush_spanned(pending):
+        with spans.span("flush"):
+            spans.count("d2h_bytes",
+                        sum(y.nbytes for y in jax.tree.leaves(pending[2])))
+            flush(*pending)
+
     pending = None
-    for t0, t1 in segments:
-        xs = build_xs(t0, t1)
+    with spans.span("run", root=True):
+        for t0, t1 in segments:
+            xs = build_xs(t0, t1)
+            if pending is not None:
+                flush_spanned(pending)
+            if pre_chunk is not None:
+                carry = pre_chunk(carry)
+            with spans.span("dispatch"):
+                spans.count("h2d_bytes", _host_bytes(xs))
+                carry, ys = chunk_fn(carry, xs)
+            spans.count("rounds", t1 - t0)
+            writeback(carry)
+            pending = (t0, t1, ys, carry)
+            if (t1 - 1) in sync_rounds:
+                flush_spanned(pending)
+                pending = None
+                on_sync(t1 - 1)
         if pending is not None:
-            flush(*pending)
-        if pre_chunk is not None:
-            carry = pre_chunk(carry)
-        carry, ys = chunk_fn(carry, xs)
-        writeback(carry)
-        pending = (t0, t1, ys, carry)
-        if (t1 - 1) in sync_rounds:
-            flush(*pending)
-            pending = None
-            on_sync(t1 - 1)
-    if pending is not None:
-        flush(*pending)
+            flush_spanned(pending)
     return carry
 
 
@@ -292,47 +317,54 @@ class ScanDriver:
         exactly as the loop engine's `step` would."""
         sampler = participation if participation is not None \
             else self.r._scen_sampler
-        if hasattr(sampler, "sample_block"):
-            masks = sampler.sample_block(t0, t1 - t0)
-        else:
-            masks = np.stack([np.asarray(sampler.sample(t), bool)
-                              for t in range(t0, t1)])
-        for row in masks:
-            self.r.stats.update(np.asarray(row, bool))
-        return np.asarray(masks, bool)
+        with spans.span("availability"):
+            if hasattr(sampler, "sample_block"):
+                masks = sampler.sample_block(t0, t1 - t0)
+            else:
+                masks = np.stack([np.asarray(sampler.sample(t), bool)
+                                  for t in range(t0, t1)])
+            for row in masks:
+                self.r.stats.update(np.asarray(row, bool))
+            return np.asarray(masks, bool)
 
     def _build_xs(self, t0: int, t1: int, participation) -> dict:
+        """The chunk's stacked inputs. The masks come first (span
+        ``availability``); the learning rates, batches and cohort padding
+        make up span ``batch_assembly``."""
         r = self.r
         self._seg = (t0, t1)
-        eta_loc, eta_srv = self._etas(t0, t1)
-        xs = {"eta_loc": eta_loc, "eta_srv": eta_srv}
-        if self.scenario_mode:
-            xs["t"] = np.arange(t0, t1, dtype=np.int32)
-            xs["batch"] = _stack([r.batcher.sample_round(t)
-                                  for t in range(t0, t1)])
+        masks = (None if self.scenario_mode
+                 else self._host_masks(t0, t1, participation))
+        with spans.span("batch_assembly"):
+            eta_loc, eta_srv = self._etas(t0, t1)
+            xs = {"eta_loc": eta_loc, "eta_srv": eta_srv}
+            if self.scenario_mode:
+                xs["t"] = np.arange(t0, t1, dtype=np.int32)
+                xs["batch"] = _stack([r.batcher.sample_round(t)
+                                      for t in range(t0, t1)])
+                return xs
+            if not r.cohort_mode:
+                xs["active"] = masks
+                xs["batch"] = _stack([r.batcher.sample_round(t)
+                                      for t in range(t0, t1)])
+                return xs
+            # cohort: reduce each mask to a padded id list + compact batch,
+            # exactly as RoundRunner.step_cohort assembles a single round
+            ids_l, valid_l = [], []
+            for j, row in enumerate(masks):
+                padded, valid = pad_cohort(np.flatnonzero(row), self.cap,
+                                           r.n_clients, t0 + j)
+                ids_l.append(padded)
+                valid_l.append(valid)
+            xs["ids"] = np.stack(ids_l)
+            xs["valid"] = np.stack(valid_l)
+            # the per-round batches die with the call, inside the span
+            xs["batch"] = _stack([
+                r.batcher.sample_round(t0 + j, client_ids=np.where(v, p, 0))
+                for j, (p, v) in enumerate(zip(ids_l, valid_l))])
+            self._last_union = np.concatenate(
+                [p[v] for p, v in zip(ids_l, valid_l)])
             return xs
-        masks = self._host_masks(t0, t1, participation)
-        if not r.cohort_mode:
-            xs["active"] = masks
-            xs["batch"] = _stack([r.batcher.sample_round(t)
-                                  for t in range(t0, t1)])
-            return xs
-        # cohort: reduce each mask to a padded id list + compact batch,
-        # exactly as RoundRunner.step_cohort assembles a single round
-        ids_l, valid_l, batch_l = [], [], []
-        for j, row in enumerate(masks):
-            padded, valid = pad_cohort(np.flatnonzero(row), self.cap,
-                                       r.n_clients, t0 + j)
-            ids_l.append(padded)
-            valid_l.append(valid)
-            batch_l.append(r.batcher.sample_round(
-                t0 + j, client_ids=np.where(valid, padded, 0)))
-        xs["ids"] = np.stack(ids_l)
-        xs["valid"] = np.stack(valid_l)
-        xs["batch"] = _stack(batch_l)
-        self._last_union = np.concatenate(
-            [p[v] for p, v in zip(ids_l, valid_l)])
-        return xs
 
     def _pre_chunk(self, carry: dict) -> dict:
         """Host-side streaming between chunks, while the device still owns
@@ -362,6 +394,8 @@ class ScanDriver:
         the next chunk's host-side xs assembly overlaps device compute.
         """
         if self.scenario_mode:
+            spans.count("d2h_bytes",
+                        carry["tau"].nbytes + carry["tau_max"].nbytes)
             self.r.stats.absorb_scan(carry["tau"], carry["tau_max"],
                                      ys["tau_sum"], ys["tau_sq_sum"])
         ys = {k: np.asarray(v) for k, v in ys.items()}

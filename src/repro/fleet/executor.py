@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.core.runner import (FLHistory, _pow2_bucket, apply_mean,
                                make_cohort_update_fn, make_dense_round_fn,
                                make_scenario_round_fn, warn_engine_fallback)
@@ -184,9 +185,10 @@ class FleetRunner:
                 batch = jax.tree.map(lambda l: l[idx], ubatch)
                 updates, losses = jax.vmap(updates_fn)(params, batch,
                                                        eta_loc)
-                state, mean_g, metrics = algo.round_step_cohort_fleet(
-                    state, ids, valid, updates, losses, rng=rngs)
-                params = jax.vmap(apply_mean)(params, mean_g, eta_srv)
+                with jax.named_scope("server_memory"):
+                    state, mean_g, metrics = algo.round_step_cohort_fleet(
+                        state, ids, valid, updates, losses, rng=rngs)
+                    params = jax.vmap(apply_mean)(params, mean_g, eta_srv)
                 return state, params, metrics
 
             self.cohort_round_fn = jax.jit(cohort_round,
@@ -642,11 +644,15 @@ class FleetScanDriver:
                 print(f"  round {t:5d} loss={np.asarray(el).mean():.4f} "
                       f"acc={np.asarray(ea).mean():.4f}")
 
+        def build_xs(t0, t1):
+            # one span: the trials' availability draws are part of it
+            with spans.span("batch_assembly"):
+                return self._build_xs(t0, t1, parts)
+
         run_pipelined_chunks(
             self._init_carry(),
             chunk_bounds(n_rounds, self.scan_chunk, evals),
-            chunk_fn=self._chunk_fn,
-            build_xs=lambda t0, t1: self._build_xs(t0, t1, parts),
+            chunk_fn=self._chunk_fn, build_xs=build_xs,
             writeback=self._writeback, flush=flush,
             sync_rounds=evals, on_sync=on_sync,
             pre_chunk=self._pre_chunk

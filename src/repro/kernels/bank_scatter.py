@@ -37,6 +37,11 @@ gather (the same trade embedding-lookup kernels make).
 Page tables and row ids reach the index maps through scalar memory
 (SMEM); `check_page_table_fits` bounds the page-table size the compiled
 paged kernels accept.
+
+Each `pallas_call` passes an explicit `name=`: the compiled custom call,
+and its event on a profiler trace's `XLA Ops` line, is named after it
+(`%_paged_bank_scatter.N`), so renaming a wrapper never renames the kernel
+that device-time readers look for.
 """
 from __future__ import annotations
 
@@ -134,6 +139,7 @@ def _bank_scatter(bank, updates, ids, valid, *, block_m, interpret):
     )
     new_bank, dsum = pl.pallas_call(
         _kernel,
+        name="_bank_scatter",
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((r, 1, m), bank.dtype),
                    jax.ShapeDtypeStruct((1, m), jnp.float32)],
@@ -202,6 +208,7 @@ def _bank_scatter_batched(banks, updates, ids, valid, *, block_m, interpret):
     )
     new_banks, dsum = pl.pallas_call(
         _kernel_batched,
+        name="_bank_scatter_batched",
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((K, r, 1, m), banks.dtype),
                    jax.ShapeDtypeStruct((K, 1, m), jnp.float32)],
@@ -295,6 +302,7 @@ def _paged_bank_scatter(pages, updates, page_table, lids, valid, *,
     )
     new_pages, dsum = pl.pallas_call(
         _paged_kernel,
+        name="_paged_bank_scatter",
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((r, 1, m), pages.dtype),
                    jax.ShapeDtypeStruct((1, m), jnp.float32)],
@@ -352,6 +360,7 @@ def _paged_bank_gather(pages, page_table, lids, *, page_size, block_m,
     )
     (out,) = pl.pallas_call(
         _paged_gather_kernel,
+        name="_paged_bank_gather",
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((c, 1, m), jnp.float32)],
         interpret=interpret,
@@ -414,6 +423,7 @@ def _paged_bank_scatter_batched(pages, updates, page_table, lids, valid, *,
     )
     new_pages, dsum = pl.pallas_call(
         _paged_kernel_batched,
+        name="_paged_bank_scatter_batched",
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((K, r, 1, m), pages.dtype),
                    jax.ShapeDtypeStruct((K, 1, m), jnp.float32)],

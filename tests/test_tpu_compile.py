@@ -11,6 +11,7 @@ module is imported: only one process at a time may load the TPU library,
 and pytest's workers import every test file.
 """
 import os
+import re
 
 import pytest
 
@@ -145,3 +146,69 @@ def test_largest_accepted_page_table_compiles(one_chip):
         _shape(one_chip, (SMEM_PAGE_TABLE_ENTRIES,), "int32"),
         _shape(one_chip, (256,), "int32"),
         _shape(one_chip, (256,), "bool"))
+
+
+# a kernel's compiled custom call is named after its `pallas_call(name=)`;
+# the roofline reader finds the paged scatter on the trace by that name
+KERNEL_NAMES = {
+    "bank_update_tree_pure": "_bank_scatter",
+    "fleet_bank_update_tree_pure": "_bank_scatter_batched",
+    "paged_bank_update_tree_pure": "_paged_bank_scatter",
+    "fleet_paged_bank_update_tree_pure": "_paged_bank_scatter_batched",
+    "paged_bank_gather_tree_pure": "_paged_bank_gather",
+}
+
+
+def _roofline_pattern() -> str:
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "bench", "metrics",
+        "bank_scatter_roofline.py")
+    with open(path) as f:
+        return re.search(r'^KERNEL = r"([^"]+)"', f.read(), re.M).group(1)
+
+
+def _op(op):
+    from repro.kernels import ops
+    return getattr(ops, op)
+
+
+def _kernel_args(one_chip, op):
+    """(kernel call, *abstract args) for op at one leaf of width 128."""
+    leaf, ps = (128,), PAGE_SIZE
+    ids = lambda lead: _shape(one_chip, lead + (COHORT,), "int32")
+    mask = lambda lead: _shape(one_chip, lead + (COHORT,), "bool")
+    table = lambda lead: _shape(one_chip, lead + (PAGE_TABLE,), "int32")
+    if op == "bank_update_tree_pure":
+        return (lambda r, u, i, v: _op(op)(r, u, i, v, interpret=False),
+                _tree(one_chip, (DENSE_ROWS,), leaf),
+                _tree(one_chip, (COHORT,), leaf), ids(()), mask(()))
+    if op == "fleet_bank_update_tree_pure":
+        k = (TRIALS,)
+        return (lambda r, u, i, v: _op(op)(r, u, i, v, interpret=False),
+                _tree(one_chip, k + (DENSE_ROWS,), leaf),
+                _tree(one_chip, k + (COHORT,), leaf), ids(k), mask(k))
+    if op == "paged_bank_gather_tree_pure":
+        return (lambda p, pt, i: _op(op)(p, pt, i, page_size=ps,
+                                           interpret=False),
+                _tree(one_chip, (_paged_rows(),), leaf), table(()), ids(()))
+    k = (TRIALS,) if op.startswith("fleet") else ()
+    return (lambda p, u, pt, i, v: _op(op)(p, u, pt, i, v, page_size=ps,
+                                             interpret=False),
+            _tree(one_chip, k + (4 * ps,), leaf),
+            _tree(one_chip, k + (COHORT,), leaf), table(k), ids(k), mask(k))
+
+
+@pytest.mark.parametrize("op", list(KERNEL_NAMES))
+def test_kernel_names_in_compiled_hlo(one_chip, op):
+    """Each kernel's custom call carries its pinned name, and only the
+    paged scatter kernels match the roofline reader's pattern."""
+    import jax
+    fn, *args = _kernel_args(one_chip, op)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    names = {m.group(1) for m in re.finditer(
+        r"^\s*(?:ROOT )?%([\w-]+)\.\d+ = [^\n]*"
+        r'custom_call_target="tpu_custom_call"', text, re.M)}
+    assert names == {KERNEL_NAMES[op]}
+    hit = re.match(_roofline_pattern(), f"%{KERNEL_NAMES[op]}.1 = ")
+    assert bool(hit) == op.startswith(("paged_bank_update",
+                                       "fleet_paged_bank_update"))
