@@ -12,7 +12,10 @@ compiled programs.
 Chunking (`scan_chunk`): the scan consumes stacked per-round inputs
 (batches, masks, learning rates), so an unchunked T-round program would
 hold T rounds of batches on device at once and could only report history
-at the very end. Chunks bound that memory by the chunk length, flush
+at the very end. A batcher that holds its client rows in one table
+(`ClientBatcher.rows()`) ships only row indices instead: the table is
+uploaded once per driver and each scan step gathers its round's batch
+from it on the device. Chunks bound that memory by the chunk length, flush
 `FLHistory` every chunk boundary, and give eval/logging host points — and
 the chunk carry is donated, so params/state buffers are reused in place
 across chunks. Chunk boundaries additionally snap to eval rounds so
@@ -113,6 +116,35 @@ def _host_bytes(tree) -> int:
                for x in jax.tree.leaves(tree) if isinstance(x, np.ndarray))
 
 
+def _row_table(batcher, mesh) -> dict | None:
+    """The batcher's client-row table where the scan can keep it on the
+    device: a batcher with a row surface (`sample_round_rows`), and a table
+    under a quarter of the device's memory where the backend reports it."""
+    if not hasattr(batcher, "sample_round_rows"):
+        return None
+    rows = batcher.rows()
+    dev = mesh.devices.flat[0] if mesh is not None else jax.devices()[0]
+    limit = (dev.memory_stats() or {}).get("bytes_limit")
+    if limit is not None and 4 * _host_bytes(rows) >= limit:
+        return None
+    return rows
+
+
+def _gather_round(x: dict, rows: dict) -> dict:
+    """One round's xs with its batch gathered from the resident row table
+    at the round's (clients, K, mb) `batch_rows`.
+
+    The gather writes step-major (K, clients, mb) rows, the order the
+    local update's scan over K reads them; the round function gets the
+    (clients, K, mb) view, and XLA cancels the two transposes, so the
+    batch is written once."""
+    x = dict(x)
+    idx = jnp.swapaxes(x.pop("batch_rows"), 0, 1)
+    x["batch"] = jax.tree.map(lambda col: jnp.swapaxes(col[idx], 0, 1),
+                              rows)
+    return x
+
+
 def pad_cohort(ids: np.ndarray, cap: int, n_clients: int,
                round_t: int) -> tuple[np.ndarray, np.ndarray]:
     """Pad one cohort's ids to the scan capacity: (padded, valid).
@@ -160,7 +192,8 @@ def run_pipelined_chunks(carry, segments, *, chunk_fn, build_xs, writeback,
 
     The loop is the root span ``run`` (`repro.spans`): it counts
     ``rounds``, ``dispatch`` times the ``chunk_fn`` call (with NumPy xs,
-    handing them to the device, and the enqueue) and adds the xs bytes to
+    handing them to the device, and the enqueue; a `ScanDriver`'s first
+    call also uploads its row table) and adds the xs bytes to
     ``h2d_bytes``, ``flush`` times each flush (the wait on the device
     included) and adds the ys bytes it reads to ``d2h_bytes``.
     """
@@ -234,9 +267,21 @@ class ScanDriver:
                 return (jax.lax.with_sharding_constraint(
                     carry, self._carry_shardings), ys)
 
-        self._chunk_fn = jax.jit(
-            lambda carry, xs: jax.lax.scan(body, carry, xs),
-            donate_argnums=(0,))
+        # a batcher with a row table ships only row indices a round; the
+        # table rides as a third, non-donated argument (never a jit
+        # constant), and each scan step gathers its own round from it
+        self._rows = _row_table(r.batcher, mesh)
+        self._rows_dev = None
+
+        def chunk(carry, xs, rows):
+            if rows is None:
+                return jax.lax.scan(body, carry, xs)
+            return jax.lax.scan(
+                lambda c, x: body(c, _gather_round(x, rows)), carry, xs)
+
+        self._scan = jax.jit(chunk, donate_argnums=(0,))
+        self._chunk_fn = lambda carry, xs: self._scan(carry, xs,
+                                                      self._device_rows())
         if r.cohort_mode:
             # one static shape for the whole program: unpinned runs pad to
             # the N-client bucket (the loop's per-round buckets vary)
@@ -296,6 +341,32 @@ class ScanDriver:
             is_leaf=lambda s: isinstance(s, jax.sharding.PartitionSpec))
         return jax.tree.map(jax.device_put, carry, self._carry_shardings)
 
+    def _device_rows(self) -> dict | None:
+        """The row table on the device, uploaded (and counted into
+        `h2d_bytes`) the first time a chunk needs it; replicated under a
+        mesh. None without a table."""
+        if self._rows is not None and self._rows_dev is None:
+            spans.count("h2d_bytes", _host_bytes(self._rows))
+            where = None
+            if self.mesh is not None:
+                from jax.sharding import NamedSharding, PartitionSpec
+                where = NamedSharding(self.mesh, PartitionSpec())
+            self._rows_dev = jax.device_put(self._rows, where)
+        return self._rows_dev
+
+    def _batches(self, rounds, ids=None) -> dict:
+        """{'batch_rows': row indices} of the listed rounds when the table
+        is resident, else {'batch': the stacked host batches}; `ids` are
+        each round's client ids (cohort mode)."""
+        ids = ids if ids is not None else [None] * len(rounds)
+        b = self.r.batcher
+        if self._rows is None:
+            return {"batch": _stack([b.sample_round(t, client_ids=i)
+                                     for t, i in zip(rounds, ids)])}
+        spans.count("table_rounds", len(rounds))
+        return {"batch_rows": _stack([b.sample_round_rows(t, client_ids=i)
+                                      for t, i in zip(rounds, ids)])}
+
     def _writeback(self, carry: dict) -> None:
         r = self.r
         r.state, r.params, r.rng = (carry["state"], carry["params"],
@@ -340,13 +411,11 @@ class ScanDriver:
             xs = {"eta_loc": eta_loc, "eta_srv": eta_srv}
             if self.scenario_mode:
                 xs["t"] = np.arange(t0, t1, dtype=np.int32)
-                xs["batch"] = _stack([r.batcher.sample_round(t)
-                                      for t in range(t0, t1)])
+                xs.update(self._batches(range(t0, t1)))
                 return xs
             if not r.cohort_mode:
                 xs["active"] = masks
-                xs["batch"] = _stack([r.batcher.sample_round(t)
-                                      for t in range(t0, t1)])
+                xs.update(self._batches(range(t0, t1)))
                 return xs
             # cohort: reduce each mask to a padded id list + compact batch,
             # exactly as RoundRunner.step_cohort assembles a single round
@@ -358,10 +427,11 @@ class ScanDriver:
                 valid_l.append(valid)
             xs["ids"] = np.stack(ids_l)
             xs["valid"] = np.stack(valid_l)
-            # the per-round batches die with the call, inside the span
-            xs["batch"] = _stack([
-                r.batcher.sample_round(t0 + j, client_ids=np.where(v, p, 0))
-                for j, (p, v) in enumerate(zip(ids_l, valid_l))])
+            # pad slots draw client 0's rows; the per-round batches die
+            # with the call, inside the span
+            xs.update(self._batches(
+                range(t0, t1),
+                [np.where(v, p, 0) for p, v in zip(ids_l, valid_l)]))
             self._last_union = np.concatenate(
                 [p[v] for p, v in zip(ids_l, valid_l)])
             return xs

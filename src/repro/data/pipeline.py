@@ -19,32 +19,54 @@ from repro.data.synthetic import make_token_stream
 
 
 class ClientBatcher:
-    """Tabular classification batches: {'x': (N,K,mb,dim), 'y': (N,K,mb)}."""
+    """Tabular classification batches: {'x': (N,K,mb,dim), 'y': (N,K,mb)}.
+
+    Two surfaces over one row table: `rows()` holds every client's rows
+    back to back (client order, `offsets[i]` the first row of client i) and
+    `sample_round_rows(t)` draws a round's rows of that table, so a scan
+    program can keep the table on the device and gather each round there
+    from indices alone; `sample_round(t)` is the host gather of the same
+    rows at the same indices.
+    """
 
     def __init__(self, X: np.ndarray, y: np.ndarray,
                  client_indices: list[np.ndarray], *, batch_size: int,
                  k_steps: int, seed: int = 0):
-        self.Xs = [X[idx] for idx in client_indices]
-        self.ys = [y[idx] for idx in client_indices]
+        self.offsets = np.cumsum([0] + [len(i) for i in client_indices])[:-1]
+        order = np.concatenate(client_indices)
+        self._rows = {"x": np.asarray(X[order], np.float32),
+                      "y": np.asarray(y[order], np.int32)}
+        # per-client views of the table
+        self.Xs = np.split(self._rows["x"], self.offsets[1:])
+        self.ys = np.split(self._rows["y"], self.offsets[1:])
         self.n_clients = len(client_indices)
         self.batch_size = batch_size
         self.k_steps = k_steps
         self.seed = seed
         self.dim = X.shape[1]
 
-    def sample_round(self, t: int, client_ids=None) -> dict:
+    def rows(self) -> dict:
+        """Every client's rows in client order: {'x': (R, dim) f32,
+        'y': (R,) int32}."""
+        return self._rows
+
+    def sample_round_rows(self, t: int, client_ids=None) -> np.ndarray:
+        """(len(ids), K, mb) int32 rows of `rows()` for round t: client i's
+        picks come from `default_rng((seed, t, i))`, offset to its rows."""
         mb, K = self.batch_size, self.k_steps
         ids = (np.arange(self.n_clients) if client_ids is None
                else np.asarray(client_ids, np.int64))
-        xs = np.empty((len(ids), K, mb, self.dim), np.float32)
-        ys = np.empty((len(ids), K, mb), np.int32)
+        out = np.empty((len(ids), K, mb), np.int32)
         for j, i in enumerate(ids):
             i = int(i)
             rng = np.random.default_rng((self.seed, t, i))
-            idx = rng.integers(0, len(self.ys[i]), size=(K, mb))
-            xs[j] = self.Xs[i][idx]
-            ys[j] = self.ys[i][idx]
-        return {"x": xs, "y": ys}
+            out[j] = self.offsets[i] + rng.integers(0, len(self.ys[i]),
+                                                    size=(K, mb))
+        return out
+
+    def sample_round(self, t: int, client_ids=None) -> dict:
+        idx = self.sample_round_rows(t, client_ids)
+        return {k: np.take(v, idx, axis=0) for k, v in self._rows.items()}
 
 
 class TokenBatcher:

@@ -1,4 +1,5 @@
 import numpy as np
+import pytest
 
 from repro.data import ClientBatcher, TokenBatcher, label_skew_partition, \
     make_classification
@@ -39,6 +40,40 @@ def test_client_batches_come_from_client_data():
     r = b.sample_round(0)
     for i in range(10):
         assert set(np.unique(r["y"][i])) <= set(labels[i])
+
+
+@pytest.mark.parametrize("ids", [None, [3, 0, 7], [2, 5, 0, 0]],
+                         ids=["full", "cohort", "cohort_pads"])
+def test_row_table_at_drawn_rows_is_the_round(ids):
+    """`rows()` taken at `sample_round_rows` is `sample_round`, for the
+    full round and for cohorts (pad slots drawing client 0's rows)."""
+    X, y = make_classification(4, 8, 50, seed=0)
+    idx, _ = label_skew_partition(y, n_clients=10, seed=0)
+    b = ClientBatcher(X, y, idx, batch_size=4, k_steps=3, seed=5)
+    rows, got = b.rows(), b.sample_round_rows(2, ids)
+    want = b.sample_round(2, client_ids=ids)
+    assert got.dtype == np.int32
+    assert got.shape == (10 if ids is None else len(ids), 3, 4)
+    for k in ("x", "y"):
+        assert rows[k][got].dtype == want[k].dtype
+        np.testing.assert_array_equal(rows[k][got], want[k])
+
+
+@pytest.mark.parametrize("seed,t", [(0, 0), (5, 7), (123, 40)])
+def test_client_batcher_draws_match_per_client_loop(seed, t):
+    """The rows drawn are those of a loop over clients, each picking
+    `default_rng((seed, t, i))` integers from its own rows (features
+    narrowed to fp32)."""
+    X, y = make_classification(4, 8, 50, seed=0)
+    idx, _ = label_skew_partition(y, n_clients=10, seed=0)
+    b = ClientBatcher(X, y, idx, batch_size=4, k_steps=3, seed=seed)
+    r = b.sample_round(t)
+    for i, rows in enumerate(idx):
+        pick = np.random.default_rng((seed, t, i)).integers(
+            0, len(rows), size=(3, 4))
+        np.testing.assert_array_equal(r["x"][i],
+                                      X[rows][pick].astype(np.float32))
+        np.testing.assert_array_equal(r["y"][i], y[rows][pick])
 
 
 def test_token_batcher_shapes_and_skew():

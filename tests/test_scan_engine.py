@@ -22,10 +22,12 @@ import jax
 import numpy as np
 import pytest
 
+from repro import spans
 from repro.bank import BankedMIFA, DenseBank, HostBank, PagedDeviceBank
-from repro.core import (MIFA, BiasedFedAvg, FedAvgSampling,
+from repro.core import (MIFA, BiasedFedAvg, FedAvgSampling, RoundRunner,
                         TraceParticipation, run_fl)
-from repro.core.scan_engine import chunk_bounds
+from repro.core.scan_engine import ScanDriver, chunk_bounds
+from repro.data import ProceduralBatcher
 from repro.fleet import Trial, run_fleet
 from repro.scenarios import GilbertElliott, HostSampler
 
@@ -165,6 +167,52 @@ def test_scan_scenario_never_touches_host_surface(tiny_problem, monkeypatch):
     assert len(hist.train_loss) == T
     assert not any(shape == (T, N) and dtype == np.bool_
                    for shape, dtype in stacked_shapes), stacked_shapes
+
+
+# --------------------------------------------------------------------------- #
+# the resident row table: indices cross the bus, rows are gathered on chip
+# --------------------------------------------------------------------------- #
+
+def _chunk_xs(model, batcher, chunk=4):
+    """Run T dense masked rounds with `batcher`; returns (each chunk's xs,
+    the run's counters)."""
+    runner = RoundRunner(model=model, algo=MIFA(memory="array"),
+                         batcher=batcher, schedule=lambda t: 0.1,
+                         weight_decay=1e-3, seed=0)
+    drv, seen = ScanDriver(runner, scan_chunk=chunk), []
+    inner = drv._chunk_fn
+
+    def recording(carry, xs):
+        seen.append(xs)
+        return inner(carry, xs)
+
+    drv._chunk_fn = recording
+    trace = np.random.default_rng(3).random((T, N)) < 0.5
+    drv.run(T, participation=TraceParticipation(trace))
+    return seen, spans.last("run")["counts"]
+
+
+def test_client_batcher_takes_the_row_table(tiny_problem):
+    """A `ClientBatcher` run ships `batch_rows` (int32 row indices) and no
+    `batch`, and counts every round as a table round."""
+    seen, counts = _chunk_xs(*tiny_problem(n_clients=N))
+    assert counts["table_rounds"] == counts["rounds"] == T
+    for xs in seen:
+        assert "batch" not in xs and xs["batch_rows"].dtype == np.int32
+    assert sum(len(xs["batch_rows"]) for xs in seen) == T
+
+
+def test_procedural_batcher_keeps_host_batches(tiny_problem):
+    """A batcher without a row table keeps the host-stacked `batch` path
+    and records no table rounds."""
+    model, ref = tiny_problem(n_clients=N)
+    batcher = ProceduralBatcher(n_clients=N, dim=ref.dim, n_classes=10,
+                                batch_size=ref.batch_size,
+                                k_steps=ref.k_steps, seed=0)
+    seen, counts = _chunk_xs(model, batcher)
+    assert counts["rounds"] == T and "table_rounds" not in counts
+    for xs in seen:
+        assert "batch_rows" not in xs and "batch" in xs
 
 
 # --------------------------------------------------------------------------- #

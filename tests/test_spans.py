@@ -9,7 +9,7 @@ leaves behind.
     whose `rounds` counter is the rounds run, and whose `h2d_bytes` /
     `d2h_bytes` equal the bytes computed from the xs and ys shapes, the
     page size and each `prepare` call's faults and evictions with their
-    pow-2 pads;
+    pow-2 pads, and the batcher's row table in a driver's first run only;
   * spans never change the numbers: a run inside a profiler session, with
     the spans on its timeline, is bit-exact against one with the spans
     stubbed out.
@@ -150,6 +150,10 @@ def _nbytes(shape, dtype) -> int:
     return int(np.prod(shape)) * np.dtype(dtype).itemsize
 
 
+def _rows_nbytes(batcher) -> int:
+    return sum(v.nbytes for v in batcher.rows().values())
+
+
 def test_scan_run_span_tree_and_rounds(tiny_problem):
     rec, calls, _, drv = _paged_run(tiny_problem)
     assert {k: v["parent"] for k, v in rec["spans"].items()} == LOOP_TREE
@@ -172,14 +176,14 @@ def test_scan_run_bus_bytes_match_shapes(tiny_problem):
     rec, calls, flushed, drv = _paged_run(tiny_problem)
     r, bank = drv.r, drv.r.algo.bank
     # xs of one round: two f32 learning rates, ids (int64, sent as int32),
-    # the valid mask and the cohort's batch
-    batch = r.batcher.sample_round(0, client_ids=np.zeros(CAP, np.int64))
+    # the valid mask and the cohort's int32 row indices; the batcher's row
+    # table once
     per_round = (2 * 4 + CAP * 4 + CAP * 1
-                 + sum(_nbytes(v.shape, v.dtype) for v in batch.values()))
+                 + CAP * r.batcher.k_steps * r.batcher.batch_size * 4)
     pages = jax.tree.leaves(r.state["bank"]["pages"])
     page_b = sum(leaf.nbytes // leaf.shape[0] for leaf in pages) * PAGE
     table_b = (bank.lp + 1) * 4
-    h2d = len(COHORTS) * per_round + sum(
+    h2d = len(COHORTS) * per_round + _rows_nbytes(r.batcher) + sum(
         _pow2_bucket(f) * page_b + table_b for f, _ in calls if f)
     d2h = sum(_pow2_bucket(e) * page_b for _, e in calls if e) + sum(
         _nbytes(s, d) for shapes in flushed for s, d in shapes)
@@ -192,7 +196,8 @@ def test_scan_run_bus_bytes_match_shapes(tiny_problem):
 def test_scenario_run_counts_the_tau_reads(tiny_problem):
     """A dense scenario run reads the carried τ state (two (N,) int32
     arrays) in every flush besides the ys; its xs are the round indices,
-    learning rates and every client's batch."""
+    learning rates and every client's row indices, after the batcher's
+    row table once."""
     model, batcher = tiny_problem(n_clients=N)
     runner = RoundRunner(model=model, algo=MIFA(memory="array"),
                          batcher=batcher, schedule=lambda t: 0.1,
@@ -210,13 +215,31 @@ def test_scenario_run_counts_the_tau_reads(tiny_problem):
     drv._flush = shaped_flush
     drv.run(9)
     rec = spans.last("run")
-    batch = batcher.sample_round(0)
-    per_round = 3 * 4 + sum(_nbytes(v.shape, v.dtype)
-                            for v in batch.values())
+    per_round = 3 * 4 + batcher.sample_round_rows(0).nbytes
     assert rec["counts"]["rounds"] == 9 and len(flushed) == 3
-    assert rec["counts"]["h2d_bytes"] == 9 * per_round
+    assert rec["counts"]["h2d_bytes"] == (9 * per_round
+                                          + _rows_nbytes(batcher))
     assert rec["counts"]["d2h_bytes"] == sum(flushed) + 3 * 2 * N * 4
     assert "availability" not in rec["spans"]      # sampled in the program
+
+
+def test_dense_run_uploads_the_row_table_once(tiny_problem):
+    """A dense masked run counts the batcher's row table into `h2d_bytes`
+    in its first `run` only; a second `run` on the same driver sends only
+    the rounds' row indices, masks and learning rates."""
+    model, batcher = tiny_problem(n_clients=N)
+    runner = RoundRunner(model=model, algo=MIFA(memory="array"),
+                         batcher=batcher, schedule=lambda t: 0.1,
+                         weight_decay=1e-3, seed=0)
+    drv, part = ScanDriver(runner, scan_chunk=2), _Trace()
+    per_round = 2 * 4 + N * 1 + batcher.sample_round_rows(0).nbytes
+    drv.run(4, participation=part)
+    first = spans.last("run")["counts"]
+    drv.run(8, participation=part, start_round=4)
+    second = spans.last("run")["counts"]
+    assert first["h2d_bytes"] == 4 * per_round + _rows_nbytes(batcher)
+    assert second["h2d_bytes"] == 4 * per_round
+    assert first["table_rounds"] == 4 == second["table_rounds"]
 
 
 def test_fleet_scan_run_is_one_root(tiny_problem):
