@@ -1,16 +1,17 @@
 """One run of one cell: set-up, warm-up, the timed window, the check.
 
 Set-up builds the program's `RoundRunner` the way `run_fl` builds it and one
-`ScanDriver` on it, from data, weights and availability the benchmark draws
-from the seed. The driver's first `compare_steps` chunks (the compared
-steps) are snapshotted for the check: chunk 0 alone, the rest in one
-pipelined driver call as in the window; further chunks warm it up until
-the cell is steady. The window is one `ScanDriver.run` call over whole
-chunks, closed by `block_until_ready` on the parameters: the program's own
-pipelined chunk flow, with no sync of the benchmark's inside it. After the
-window the peak memory is read, the program's state freed, and the plain
-reference the configuration names (`reference/<name>.py`) replays the
-compared steps from the same seed to decide `correct` (`check.py`).
+`ScanDriver` on it, on the mesh the configuration names if it names one,
+from data, weights and availability the benchmark draws from the seed. The
+driver's first `compare_steps` chunks (the compared steps) are snapshotted
+for the check: chunk 0 alone, the rest in one pipelined driver call as in
+the window; further chunks warm it up until the cell is steady. The window
+is one `ScanDriver.run` call over whole chunks, closed by
+`block_until_ready` on the parameters: the program's own pipelined chunk
+flow, with no sync of the benchmark's inside it. After the window the peak
+memory is read, the program's state freed, and the plain reference the
+configuration names (`reference/<name>.py`) replays the compared steps from
+the same seed to decide `correct` (`check.py`).
 
 The benchmark's host spans wrap the program's calls from outside (a proxy
 around the batcher, the availability sampler, the paged bank's `prepare`,
@@ -87,13 +88,19 @@ class Spans:
 
 
 class BatcherProxy:
-    """The program's batcher, its `sample_round` inside a span."""
+    """The program's batcher, its `sample_round` inside a span, and its
+    `sample_round_rows` too where it has one. That one is wrapped on lookup,
+    so the proxy has it exactly when the batcher does: `ScanDriver` keeps a
+    row table for a batcher that has it."""
 
     def __init__(self, inner, spans: Spans):
         self._inner, self._spans = inner, spans
 
     def __getattr__(self, name):
-        return getattr(self._inner, name)
+        attr = getattr(self._inner, name)
+        if name == "sample_round_rows":
+            return _wrap(attr, self._spans, "batch_assembly")
+        return attr
 
     def sample_round(self, t, client_ids=None):
         with self._spans("batch_assembly"):
@@ -127,6 +134,24 @@ class SamplerProxy:
         for j, mask in enumerate(masks):
             self._record(t0 + j, mask)
         return masks
+
+
+def make_mesh(cell: workload.Cell):
+    """The mesh the cell's configuration names (`"mesh"`: axis sizes for
+    `make_host_mesh`, or null for none) over its first `chips` devices."""
+    axes = cell.config.get("mesh")
+    if axes is None:
+        return None
+    if not jax.config.jax_threefry_partitionable:
+        # the program's in-round draws would depend on the mesh, and the
+        # reference's availability draws would no longer match them
+        raise ValueError("a mesh needs jax_threefry_partitionable")
+    from repro.launch.mesh import make_host_mesh
+    mesh = make_host_mesh(**axes)
+    if mesh.size != cell.chips:
+        raise ValueError(f"{cell.name}: mesh {axes} spans {mesh.size} "
+                         f"devices, the cell holds {cell.chips} chips")
+    return mesh
 
 
 def _paged(bank) -> bool:
@@ -174,6 +199,12 @@ def build(cell: workload.Cell, seed: int, spans: Spans):
     a = dict(cfg["algorithm"])
     algo = make_algorithm(a.pop("name"), n=n, **a)
     params0 = model.init_params(jax.random.PRNGKey(seed), cfg)
+    mesh = make_mesh(cell)
+    bank = getattr(algo, "bank", None)
+    if mesh is not None and hasattr(bank, "mesh") and bank.mesh is None:
+        # as `run_fl` does: a mesh-less bank takes the run's mesh before
+        # `RoundRunner` has it lay out its rows
+        bank.mesh, bank.cfg = mesh, arch
     runner = RoundRunner(
         model=build_model(arch), algo=algo,
         batcher=BatcherProxy(batcher, spans), schedule=workload.schedule(cfg),
@@ -191,10 +222,10 @@ def build(cell: workload.Cell, seed: int, spans: Spans):
     else:
         runner._scen_sampler = sampler = SamplerProxy(
             runner._scen_sampler, spans, keep)
-    bank = getattr(algo, "bank", None)
     if _paged(bank):
         bank.prepare = _wrap(bank.prepare, spans, "paging")
-    driver = ScanDriver(runner, scan_chunk=cfg["scan_chunk"])
+    driver = ScanDriver(runner, scan_chunk=cfg["scan_chunk"], mesh=mesh,
+                        cfg=arch)
     driver._chunk_fn = _wrap(driver._chunk_fn, spans, "dispatch")
     driver._flush = _wrap(driver._flush, spans, "flush")
     return {"runner": runner, "driver": driver, "bank": bank,

@@ -1,4 +1,6 @@
-"""Host milliseconds per round spent in the batcher's `sample_round`."""
+"""Host milliseconds per round spent in the batcher's `sample_round`, or in
+its `sample_round_rows` where the driver gathers batches on the chip from
+the batcher's row table and ships row indices (`harness.BatcherProxy`)."""
 
 
 def read(ctx):

@@ -67,3 +67,28 @@ def test_traced_run_reports_layers_and_breakdown():
     assert res["device"]["window_s"] > 0 and "busy_s" in res["device"]
     assert set(res["breakdown"]) == {"device_ops", "idle_gaps"}
     assert list(res)[-1] == "checks"
+
+
+@pytest.mark.parametrize("name,rows", [("tiny_dense.skew", True),
+                                       ("tiny_paged.fresh", False)])
+def test_batcher_proxy_times_the_row_sampler_only_where_it_exists(name,
+                                                                  rows):
+    spans = harness.Spans()
+    prog = harness.build(tiny(name), workload.program_seed(3), spans)
+    proxy = prog["runner"].batcher
+    assert isinstance(proxy, harness.BatcherProxy)
+    # the driver keeps a row table exactly for a batcher with a row sampler
+    assert hasattr(proxy, "sample_round_rows") is rows
+    assert (prog["driver"]._rows is not None) is rows
+    if rows:
+        spans.on = True
+        assert proxy.sample_round_rows(0, client_ids=[0, 1]).shape[0] == 2
+        assert spans.seconds["batch_assembly"] > 0
+
+
+def test_traced_dense_run_reads_batch_host_ms():
+    res = harness.run_cell(tiny("tiny_dense.skew"), 5, 0.3, True,
+                           t_start=time.perf_counter(),
+                           peaks=PEAKS)["result"]
+    assert res["correct"], res["checks"]
+    assert res["metrics"]["batch_host_ms"]["value"] > 0

@@ -29,7 +29,13 @@ PUBLISHED = {"mlp_dense_n100": {"d_model": 3072, "d_ff": 128,
                                 "k_steps": 5, "batch_size": 100},
              "mlp_paged_n100k": {"d_model": 256, "d_ff": 128,
                                  "n_classes": 10, "n_params": 50_698,
-                                 "k_steps": 5, "batch_size": 100}}
+                                 "k_steps": 5, "batch_size": 100},
+             "mlp_sharded_n100k": {"d_model": 256, "d_ff": 128,
+                                   "n_classes": 10, "n_params": 50_698,
+                                   "k_steps": 5, "batch_size": 100}}
+CONFIG_FILES = sorted(
+    f[:-5] for f in os.listdir(os.path.join(BENCH, "configs"))
+    if f.endswith(".json"))
 
 
 @pytest.fixture(scope="module")
@@ -116,6 +122,20 @@ def test_configs_keep_widths_and_declare_cuts(spec):
         for key in WIDTHS:
             assert cfg[key] == PUBLISHED[c["name"]][key], (c["name"], key)
         assert not set(c["reduced"]) & set(WIDTHS)
+
+
+@pytest.mark.parametrize("name", CONFIG_FILES)
+def test_every_config_file_keeps_its_published_widths(name):
+    """Also a configuration that no cell runs yet: it waits at its
+    source's widths, its cuts declared, its mesh null or axis sizes."""
+    with open(os.path.join(BENCH, "configs", name + ".json")) as f:
+        cfg = json.load(f)
+    assert {key: cfg[key] for key in WIDTHS} == PUBLISHED[name]
+    assert not set(cfg["reduced"]) & set(WIDTHS)
+    assert set(cfg["reduced"]) <= set(cfg["assumed"])
+    mesh = cfg["mesh"]
+    assert mesh is None or (set(mesh) <= {"data", "model"} and all(
+        isinstance(v, int) and v >= 1 for v in mesh.values()))
 
 
 def test_every_cell_loads_by_name(spec):

@@ -4,6 +4,7 @@ recorded on the chip."""
 import os
 import re
 import sys
+from types import SimpleNamespace
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 for _p in (BENCH, os.path.join(os.path.dirname(BENCH), "src")):
@@ -115,3 +116,51 @@ def test_recorded_v5e_trace():
     assert tr.op_seconds(events, kernel) == pytest.approx(0.011850151)
     assert tr.top_ops(events, top=1)[0][0] == \
         "%_paged_bank_scatter.3 custom-call"
+
+
+def _collective_events():
+    host, d0, d1 = "/host:CPU", "/device:TPU:0", "/device:TPU:1"
+    return [
+        ev(host, "window", 0, 100, "python"),
+        # as a v5e trace names it: the sharded bank's cohort gather
+        ev(d0, "%all-reduce.26 = (f32[320,128]{1,0:T(8,128)}, "
+               "f32[320,256,128]{2,1,0:T(8,128)}) all-reduce(f32[320,128]"
+               "{1,0:T(8,128)S(1)} %get-tuple-element.1418, f32[320,256,128]"
+               "{2,1,0:T(8,128)} %get-tuple-element.1419)", 10, 4),
+        ev(d1, "%all-reduce.26 = (f32[320,128]{1,0}) all-reduce(f32[320,"
+               "128]{1,0} %get-tuple-element.1418)", 10, 4),
+        ev(d0, "%all-gather-start.3 = (f32[8]{0}, f32[32]{0}) "
+               "all-gather-start(f32[8]{0} %p)", 20, 1),
+        ev(d0, "%all-gather-done.3 = f32[32]{0} all-gather-done((f32[8]{0}, "
+               "f32[32]{0}) %all-gather-start.3)", 21, 2),
+        ev(d0, "%reduce-scatter.2 = f32[8]{0} reduce-scatter(f32[32]{0} %p)",
+           30, 1),
+        ev(d0, "%collective-permute.1 = f32[8]{0} collective-permute(f32[8]"
+               "{0} %p)", 40, 1),
+        ev(d0, "%all-to-all.5 = f32[8]{0} all-to-all(f32[8]{0} %p)", 50, 1),
+        # what only reads a collective's result, or is async and no
+        # collective, or runs outside the window, does not count
+        ev(d0, "%fusion.9 = f32[320,128]{1,0} fusion(f32[320,128]{1,0} "
+               "%all-reduce.26), kind=kLoop", 60, 5),
+        ev(d0, "%copy-start.2 = (f32[8]{0}, f32[8]{0}) copy-start(f32[8]{0} "
+               "%p)", 70, 3),
+        ev(d1, "%all-reduce.27 = f32[8]{0} all-reduce(f32[8]{0} %p)", 120, 9),
+    ]
+
+
+def test_collective_ms_counts_collectives_by_opcode():
+    import workload
+    reader = workload.plugin("metrics", "collective_ms")
+    ctx = SimpleNamespace(events=_collective_events(), chips=2, rounds=2)
+    # chip 0: 4 + 1 + 2 + 1 + 1 + 1 ms, chip 1: 4 ms; over 2 chips, 2 rounds
+    assert reader.read(ctx) == pytest.approx(3.5)
+
+
+def test_collective_ms_reads_nothing_without_collectives(events):
+    import workload
+    reader = workload.plugin("metrics", "collective_ms")
+    no_coll = [e for e in events if "all-reduce" not in e["name"]]
+    assert reader.read(SimpleNamespace(events=no_coll, chips=2,
+                                       rounds=2)) is None
+    assert reader.read(SimpleNamespace(events=None, chips=2,
+                                       rounds=2)) is None
