@@ -13,9 +13,21 @@ million-client runs hold no per-client state at all.
 """
 from __future__ import annotations
 
+import os
+from concurrent.futures import ThreadPoolExecutor
+
 import numpy as np
 
+from repro import spans
 from repro.data.synthetic import make_token_stream
+
+
+def _usable_cores() -> int:
+    """The cores this process may run on: its CPU affinity where the
+    platform reports one, else the machine's count."""
+    if hasattr(os, "sched_getaffinity"):
+        return len(os.sched_getaffinity(0))
+    return os.cpu_count() or 1
 
 
 class ClientBatcher:
@@ -110,6 +122,15 @@ class ProceduralBatcher:
     a fixed random linear teacher. Identical draws whether a client is
     sampled via the full path or a compact cohort, so ProceduralBatcher is a
     drop-in for ClientBatcher at any N.
+
+    `sample_round` draws each distinct id of the call once, at its first
+    slot, and copies it to the slots that repeat it (a cohort's pad slots
+    all point at client 0). The distinct ids are spread over one thread per
+    usable core, capped at their number, each thread writing its own slots;
+    with one thread the loop runs inline. Every client keeps its own
+    streams, so the result is bit for bit the same for any thread count.
+    Each call adds its distinct ids to counter `procedural_draws`
+    (`repro.spans`).
     """
 
     def __init__(self, *, n_clients: int, dim: int, n_classes: int = 2,
@@ -137,13 +158,28 @@ class ProceduralBatcher:
                else np.asarray(client_ids, np.int64))
         xs = np.empty((len(ids), K, mb, self.dim), np.float32)
         ys = np.empty((len(ids), K, mb), np.int32)
-        for j, i in enumerate(ids):
-            i = int(i)
-            rng = np.random.default_rng((self.seed, t, i))
-            x = rng.normal(size=(K, mb, self.dim)).astype(np.float32) \
-                * self.noise + self._client_mean(i)
-            xs[j] = x
-            ys[j] = np.argmax(x @ self.teacher, axis=-1).astype(np.int32)
+        _, first, inverse = np.unique(ids, return_index=True,
+                                      return_inverse=True)
+        spans.count("procedural_draws", len(first))
+
+        def draw(slots):
+            for j in slots:
+                i = int(ids[j])
+                rng = np.random.default_rng((self.seed, t, i))
+                x = rng.normal(size=(K, mb, self.dim)).astype(np.float32) \
+                    * self.noise + self._client_mean(i)
+                xs[j] = x
+                ys[j] = np.argmax(x @ self.teacher, axis=-1).astype(np.int32)
+
+        n = min(_usable_cores(), len(first))
+        if n <= 1:
+            draw(first)
+        else:
+            with ThreadPoolExecutor(n) as pool:
+                list(pool.map(draw, [first[w::n] for w in range(n)]))
+        src = first[inverse]
+        rep = np.flatnonzero(src != np.arange(len(ids)))
+        xs[rep], ys[rep] = xs[src[rep]], ys[src[rep]]
         return {"x": xs, "y": ys}
 
 

@@ -83,6 +83,29 @@ def assert_fleet_trial_close(fleet, seq_run, k):
     assert hist_k.n_active == seq_run[1].n_active
 
 
+def serial_procedural_round(b, t, client_ids=None) -> dict:
+    """`ProceduralBatcher.sample_round` of `b` as the serial loop over
+    every slot that it once was, frozen here as the oracle of its bits:
+    each slot draws its own client's streams, repeats included."""
+    import numpy as np
+    mb, K, d = b.batch_size, b.k_steps, b.dim
+    teacher = np.random.default_rng((b.seed, 0x7EAC)).normal(
+        size=(d, b.n_classes)).astype(np.float32)
+    ids = (np.arange(b.n_clients) if client_ids is None
+           else np.asarray(client_ids, np.int64))
+    xs = np.empty((len(ids), K, mb, d), np.float32)
+    ys = np.empty((len(ids), K, mb), np.int32)
+    for j, i in enumerate(ids):
+        i = int(i)
+        mean = (b.shift * np.random.default_rng(
+            (b.seed, 0xC11E27, i)).normal(size=d)).astype(np.float32)
+        x = np.random.default_rng((b.seed, t, i)).normal(
+            size=(K, mb, d)).astype(np.float32) * b.noise + mean
+        xs[j] = x
+        ys[j] = np.argmax(x @ teacher, axis=-1).astype(np.int32)
+    return {"x": xs, "y": ys}
+
+
 def pytest_addoption(parser):
     parser.addoption("--runslow", action="store_true", default=False,
                      help="run tests marked @pytest.mark.slow")

@@ -1,8 +1,13 @@
+import threading
+
 import numpy as np
 import pytest
+from conftest import serial_procedural_round
 
-from repro.data import ClientBatcher, TokenBatcher, label_skew_partition, \
-    make_classification
+from repro import spans
+from repro.data import ClientBatcher, ProceduralBatcher, TokenBatcher, \
+    label_skew_partition, make_classification
+from repro.data import pipeline
 
 
 def test_label_skew_two_classes_equal_sizes():
@@ -96,3 +101,73 @@ def test_classification_train_test_same_distribution():
         mtr = Xtr[ytr == c].mean(0)
         mte = Xte[yte == c].mean(0)
         assert np.linalg.norm(mtr - mte) < 0.5 * np.linalg.norm(mtr) + 0.5
+
+
+# --------------------------------------------------------------------------- #
+# ProceduralBatcher: bit for bit the frozen serial loop
+# --------------------------------------------------------------------------- #
+
+SMALL = dict(n_clients=64, dim=16, n_classes=10, batch_size=8, k_steps=2)
+# the paged benchmark cells' widths: d 256, K 5, mb 100, 10 classes
+REAL = dict(n_clients=100_000, dim=256, n_classes=10, batch_size=100,
+            k_steps=5)
+_perm = np.random.default_rng(11)
+COHORTS = {
+    "full": (SMALL, None),
+    "sorted": (SMALL, np.sort(_perm.choice(64, 20, replace=False))),
+    "unsorted": (SMALL, _perm.permutation(64)[:25]),
+    "repeats": (SMALL, [5, 9, 5, 0, 9, 9, 3, 0]),
+    "pads": (SMALL, np.concatenate([_perm.choice(np.arange(1, 64), 30,
+                                                 replace=False),
+                                    np.zeros(80, np.int64)])),
+    "pads_real_width": (REAL, np.concatenate(
+        [_perm.choice(100_000, 60, replace=False), np.zeros(80, np.int64)])),
+    "single": (SMALL, [17]),
+    "empty": (SMALL, []),
+}
+
+
+@pytest.mark.parametrize("seed,t", [(0, 0), (5, 7), (2**31 + 9, 40)])
+@pytest.mark.parametrize("case", list(COHORTS))
+def test_procedural_batcher_is_the_serial_loop(case, seed, t):
+    """Every slot's features and labels are those of the serial loop over
+    slots, for the full population and for sorted, unsorted and repeating
+    cohorts (80 pad slots of client 0 among them)."""
+    widths, ids = COHORTS[case]
+    b = ProceduralBatcher(**widths, shift=1.0, noise=1.0, seed=seed)
+    got = b.sample_round(t, client_ids=ids)
+    want = serial_procedural_round(b, t, ids)
+    for k in ("x", "y"):
+        assert got[k].dtype == want[k].dtype
+        assert np.array_equal(got[k], want[k]), k
+
+
+@pytest.mark.parametrize("cores", [1, 2, 3, 8, 64])
+def test_procedural_batcher_bits_do_not_depend_on_the_threads(cores,
+                                                              monkeypatch):
+    """With the core count forced to one (the loop runs inline, no pool)
+    or to several, the round is the serial loop's, and no thread is left
+    running."""
+    monkeypatch.setattr(pipeline, "_usable_cores", lambda: cores)
+    if cores == 1:
+        def no_pool(*a, **k):
+            raise AssertionError("one core must draw inline")
+        monkeypatch.setattr(pipeline, "ThreadPoolExecutor", no_pool)
+    b = ProceduralBatcher(**SMALL, shift=0.7, noise=1.3, seed=3)
+    ids = np.concatenate([np.arange(40, 0, -3), np.zeros(6, np.int64)])
+    before = threading.active_count()
+    got = b.sample_round(4, client_ids=ids)
+    assert threading.active_count() == before
+    want = serial_procedural_round(b, 4, ids)
+    for k in ("x", "y"):
+        assert np.array_equal(got[k], want[k]), k
+
+
+def test_procedural_batcher_counts_its_distinct_draws():
+    """`procedural_draws` adds the call's distinct ids, pads once, to the
+    open root; outside a root nothing is kept."""
+    b = ProceduralBatcher(**SMALL, seed=0)
+    with spans.span("r", root=True):
+        b.sample_round(0, client_ids=[4, 0, 4, 0, 0, 7])
+        b.sample_round(1)
+    assert spans.last("r")["counts"]["procedural_draws"] == 3 + 64

@@ -21,11 +21,14 @@ import jax
 import numpy as np
 import pytest
 
+from conftest import serial_procedural_round
+
 from repro import spans
-from repro.bank import BankedMIFA, PagedDeviceBank
+from repro.bank import BankedMIFA, DenseBank, PagedDeviceBank
 from repro.core import MIFA, RoundRunner
 from repro.core.runner import _pow2_bucket
 from repro.core.scan_engine import ScanDriver
+from repro.data import ProceduralBatcher
 from repro.fleet import Trial, run_fleet
 from repro.scenarios import GilbertElliott
 
@@ -275,3 +278,38 @@ def test_spans_leave_the_trajectory_bit_exact(tiny_problem, tmp_path,
     names = {e.name for p in pd.planes for line in p.lines
              for e in line.events}
     assert set(LOOP_TREE) | {"run"} <= names
+
+
+class _SerialProcedural(ProceduralBatcher):
+    """The frozen serial loop in place of `sample_round`."""
+
+    def sample_round(self, t, client_ids=None):
+        return serial_procedural_round(self, t, client_ids)
+
+
+def _procedural_run(tiny_problem, batcher_cls):
+    """A cohort-mode ScanDriver run over COHORTS at capacity 4, so every
+    round pads with client 0; returns (record, runner)."""
+    model, ref = tiny_problem(n_clients=N)
+    batcher = batcher_cls(n_clients=N, dim=ref.dim, n_classes=10,
+                          batch_size=ref.batch_size, k_steps=ref.k_steps,
+                          seed=2)
+    runner = RoundRunner(model=model, algo=BankedMIFA(DenseBank()),
+                         batcher=batcher, schedule=lambda t: 0.1 / (1 + t),
+                         weight_decay=1e-3, seed=0, cohort_capacity=4)
+    ScanDriver(runner, scan_chunk=4).run(len(COHORTS), participation=_Trace())
+    return spans.last("run"), runner
+
+
+def test_procedural_draws_count_each_rounds_distinct_ids(tiny_problem):
+    """`procedural_draws` is the sum over rounds of the distinct ids each
+    round's batch draws, the pads' client 0 once; the trajectory is the
+    frozen serial batcher's to the bit."""
+    rec, got = _procedural_run(tiny_problem, ProceduralBatcher)
+    assert rec["counts"]["procedural_draws"] == sum(
+        len(set(c) | {0}) for c in COHORTS)
+    _, want = _procedural_run(tiny_problem, _SerialProcedural)
+    for a, b in zip(jax.tree.leaves((got.params, got.state)),
+                    jax.tree.leaves((want.params, want.state))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert got.hist.train_loss == want.hist.train_loss
